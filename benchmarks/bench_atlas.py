@@ -258,6 +258,8 @@ def main() -> None:
                     "'ci' subsamples topo_seeds/seeds at the same horizon "
                     "so the gate fits the CI job budget")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     table = run(print, stream_out=args.stream_out, preset=args.preset)
     if args.out:
         with open(args.out, "w") as f:

@@ -95,7 +95,7 @@ EFFICIENCY_GATES = {
 
 
 #: Backend-comparison sweep (DESIGN.md §7): the same jobs through the XLA
-#: oracle and the fused Pallas slot kernels (interpret mode on CPU), timed
+#: oracle and the fused Pallas slot kernels (compiled on a TPU), timed
 #: side by side and gated on bit-exact metric parity by check_bench.
 BACKEND_COMPARE = dict(scenario="paper_grid", policy="pi3_reg", eps_b=0.05,
                        n_jobs=8, lam0=4.0, dlam=0.25, T=512, chunk=128)
@@ -367,6 +367,8 @@ def main() -> None:
                     help="checkpoint dir for the resilience overhead "
                     "section (uploaded in the CI bench artifact)")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     table = run(print, preset=args.preset, stream_out=args.stream_out,
                 ckpt_dir=args.ckpt_dir)
     if args.out:

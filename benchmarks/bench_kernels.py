@@ -1,5 +1,6 @@
-"""Kernel benchmarks: interpret-mode Pallas vs jnp reference (correctness +
-CPU timing; real speed lives on TPU — the derived column reports max error).
+"""Kernel benchmarks: Pallas kernels vs jnp reference (correctness +
+timing; on the CPU the kernels run in the interpreter, so its times say
+nothing about the chip — the derived column reports max error).
 
 Emits a JSON table (``--out BENCH_kernels.json``) of per-kernel µs that
 `scripts/check_bench.py` diffs against the committed
@@ -139,6 +140,8 @@ def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--out", default=None, help="write the JSON table here")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     table = run(print)
     if args.out:
         with open(args.out, "w") as f:

@@ -55,8 +55,8 @@ SERVING_OVERLOAD_MIN_SHED = 0.10
 SERVING_OVERLOAD_RATE_SLACK = 1.05
 
 #: Backend-parity sweep: the same serving jobs under the XLA oracle and
-#: the fused Pallas slot kernels (interpret mode on CPU) must agree
-#: bit-exactly on every finalize leaf.
+#: the fused Pallas slot kernels must agree bit-exactly on every finalize
+#: leaf.
 SERVING_PARITY = dict(scenario="paper_grid", policy="pi3_reg",
                       trace="bursty", rate_frac=0.95, n_jobs=4,
                       T=1024, chunk=256, eps_b=0.05)
@@ -80,8 +80,7 @@ def parity_section(emit) -> dict:
     for backend in ("xla", "pallas"):
         jobs = [ServingJob(scenario=c["scenario"], policy=c["policy"],
                            trace=c["trace"], lam=c["rate_frac"] * bound,
-                           seed=s, eps_b=c["eps_b"], backend=backend,
-                           interpret=True)
+                           seed=s, eps_b=c["eps_b"], backend=backend)
                 for s in range(c["n_jobs"])]
         run_serving(jobs, T=c["T"], chunk=c["chunk"])        # warm-up
         t0 = time.time()
@@ -119,6 +118,17 @@ def run(emit) -> dict:
     run.stream_records = result.stream_records   # for main()'s JSONL writer
     run.write_stream_jsonl = write_stream_jsonl
 
+    check_gates(rep, emit)
+    rep["parity"] = parity_section(emit)
+    emit(f"serving/sweep,{table['us_per_sim']:.0f},"
+         f"n_sims={rep['n_sims']} wall_s={wall:.1f} "
+         f"stream_records={rep['stream_records']}")
+    return table
+
+
+def check_gates(rep: dict, emit) -> None:
+    """Assert the nominal-load and overload gates on a `serving_report`
+    of SERVING_SMOKE (rows "0.95" and SERVING_OVERLOAD_FRAC)."""
     bound = rep["bound_exact"]
     for frac, row in rep["rows"].items():
         emit(f"serving/smoke/{frac},,offered={row['offered']:.3f} "
@@ -151,12 +161,6 @@ def run(emit) -> dict:
          f"shed<={SERVING_MAX_SHED} p99<={SERVING_P99_MAX:.0f} "
          f"overload_shed>={SERVING_OVERLOAD_MIN_SHED}: pass")
 
-    rep["parity"] = parity_section(emit)
-    emit(f"serving/sweep,{table['us_per_sim']:.0f},"
-         f"n_sims={rep['n_sims']} wall_s={wall:.1f} "
-         f"stream_records={rep['stream_records']}")
-    return table
-
 
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
@@ -164,6 +168,8 @@ def main() -> None:
     ap.add_argument("--stream-out", default=None,
                     help="write per-chunk stream records as JSONL here")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     table = run(print)
     if args.out:
         with open(args.out, "w") as f:

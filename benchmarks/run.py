@@ -6,7 +6,7 @@ benchmarks.  Prints ``name,us_per_call,derived`` CSV lines.
   table_capacity  Theorem 1/4 LP vs simulated saturation + pairing models
   bench_router    backpressure MoE router vs aux-loss vs plain
   bench_serving   backpressure serving scheduler vs RR/JSQ
-  bench_kernels   Pallas kernels (interpret) vs jnp references
+  bench_kernels   Pallas kernels vs jnp references
 
 Usage: PYTHONPATH=src python -m benchmarks.run [suite ...]
 """
@@ -42,6 +42,8 @@ def main() -> None:
         pass
 
     chosen = sys.argv[1:] or list(suites)
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     failures = []
     print("name,us_per_call,derived")
     for name in chosen:

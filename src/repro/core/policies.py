@@ -64,8 +64,6 @@ class PolicyConfig:
                                  # weighted by differential backlog [17,18]
     backend: str = "xla"         # "xla" | "pallas" — slot-decision kernels
                                  # (DESIGN.md §7); bit-identical outputs
-    interpret: bool = True       # Pallas interpreter mode (CPU CI); pass
-                                 # False on TPU for compiled kernels
 
     def __post_init__(self):
         if self.name not in KNOWN_POLICIES:
@@ -126,8 +124,8 @@ def greedy_maximal_matching(edges: jnp.ndarray, weights: jnp.ndarray,
 
 
 def bp_route_slot(sp: StaticProblem, state: NetState,
-                  wireless: bool = False, backend: str = "xla",
-                  interpret: bool = True) -> Tuple[NetState, Dict]:
+                  wireless: bool = False, backend: str = "xla"
+                  ) -> Tuple[NetState, Dict]:
     """One slot of max-differential-backlog routing over every link.
 
     Per undirected link, the class (i, n) maximizing |Q_m - Q_k| gets the full
@@ -151,7 +149,7 @@ def bp_route_slot(sp: StaticProblem, state: NetState,
 
     Qf = Q.reshape(Q.shape[0], -1)                 # [N, 3*NC] (i-major)
     if backend == "pallas":
-        best, dmax = slot_route_decide(Qf, m_idx, l_idx, interpret=interpret)
+        best, dmax = slot_route_decide(Qf, m_idx, l_idx)
     else:
         best, dmax = slot_route_ref(Qf, m_idx, l_idx)
     best_i = best // NC
@@ -251,7 +249,7 @@ def _comp_balance_kernel_call(sp: StaticProblem, cfg: PolicyConfig,
         state.X[:, 0], state.X[:, 1],
         state.cum_arr[:, 0], state.cum_arr[:, 1], state.cum_comb, x_net,
         pairing=cfg.pairing, thresholded=cfg.thresholded,
-        threshold=cfg.threshold, interpret=cfg.interpret)
+        threshold=cfg.threshold)
 
 
 def _inject_processed(sp: StaticProblem, state: NetState, amount: jax.Array,
@@ -374,7 +372,7 @@ def slot_step(sp: StaticProblem, cfg: PolicyConfig, state: NetState,
     kernels, bit-identical; DESIGN.md §7)."""
     state, assigned, m1 = load_balance_slot(sp, cfg, state, arrivals, eps_b)
     state, m2 = bp_route_slot(sp, state, wireless=cfg.wireless,
-                              backend=cfg.backend, interpret=cfg.interpret)
+                              backend=cfg.backend)
     state, m3 = computation_slot(sp, cfg, state, assigned, key, eps_b)
     metrics = {
         "total_queue": state.total_queue(),

@@ -632,10 +632,7 @@ def sweep_lambda_max(cells: Sequence[AtlasJob], *,
             # One readout per policy group, after its *last* bucket: the
             # jit cache holds one trace per bucket shape, so summing per
             # bucket would double-count earlier buckets of the same group.
-            try:
-                n_step_compiles += int(step_fn._cache_size())
-            except Exception:  # pragma: no cover - private API moved
-                n_step_compiles = -10 ** 6
+            n_step_compiles += int(step_fn._cache_size())
 
         if rt is not None and rt.should_snapshot(n_launches):
             # Unit-end marker: empty carry, cursor at the next unit's

@@ -43,8 +43,7 @@ from typing import Dict, List, NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.runtime.fault import RecoveryPlan, plan_recovery
 
@@ -75,15 +74,13 @@ class FleetJob:
     fixed_node: int = 0
     backend: str = "xla"          # slot-decision backend: "xla" | "pallas"
                                   # (fused tiled kernels, DESIGN.md §7)
-    interpret: bool = True        # Pallas interpreter mode — True on CPU CI,
-                                  # False for compiled kernels on TPU
 
     def policy_config(self) -> PolicyConfig:
         return PolicyConfig(
             name=self.policy, eps_b=self.eps_b, pairing=self.pairing,
             threshold=self.threshold, fixed_node=self.fixed_node,
             wireless=get_scenario(self.scenario).wireless,
-            backend=self.backend, interpret=self.interpret)
+            backend=self.backend)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -465,17 +462,25 @@ def make_group_launch(runner, mesh: Mesh, n_step_args: int = 7):
     reuse the compiled programs instead of re-tracing, and within one sweep
     the chunk loop is guaranteed a single compilation
     (`tests/test_fleet.py::TestNoRecompilation`)."""
-    spec = P("fleet")
-
-    def _sharded(fn, n_in):
-        return shard_map(jax.vmap(fn), mesh=mesh, in_specs=(spec,) * n_in,
-                         out_specs=spec,
-                         check_rep=False)  # scan carries: no replication rule
-    init_fn = jax.jit(_sharded(runner.init_carry, 1))
-    step_fn = jax.jit(_sharded(runner.chunk_step, n_step_args),
-                      donate_argnums=(n_step_args - 1,))
-    fin_fn = jax.jit(_sharded(runner.finalize, 3))
+    init_fn = _lane_jit(runner.init_carry, mesh, 1)
+    step_fn = _lane_jit(runner.chunk_step, mesh, n_step_args,
+                        donate_argnums=(n_step_args - 1,))
+    fin_fn = _lane_jit(runner.finalize, mesh, 3)
     return init_fn, step_fn, fin_fn
+
+
+def _lane_jit(fn, mesh: Mesh, n_in: int, **jit_kw):
+    """`jax.jit(shard_map(vmap(fn)))` over the `"fleet"` lane axis.
+
+    Outputs are declared lane-sharded.  Left to itself, a jit over a
+    one-device mesh reports its outputs as replicated (`P()`), which no
+    longer matches a carry restored as `P("fleet")` from a checkpoint —
+    and a different input sharding is a second compiled program."""
+    return jax.jit(
+        jax.shard_map(jax.vmap(fn), mesh=mesh,
+                      in_specs=(P("fleet"),) * n_in, out_specs=P("fleet"),
+                      check_vma=False),        # scan carries: no vma rule
+        out_shardings=NamedSharding(mesh, P("fleet")), **jit_kw)
 
 
 @functools.lru_cache(maxsize=64)
@@ -500,8 +505,6 @@ def make_sim_rewriter(runner, mesh: Mesh):
 
     Memoized on `(runner, mesh)` like the launch programs: one compiled
     rewrite per policy group, asserted by the atlas single-compile test."""
-    spec = P("fleet")
-
     def rewrite(pp, reset, park, carry):
         fresh = runner.init_carry(pp)
         state, stats, drift, mod, t = jax.tree_util.tree_map(
@@ -510,32 +513,30 @@ def make_sim_rewriter(runner, mesh: Mesh):
             park, jnp.int32(VERDICT_UNSTABLE), drift.verdict))
         return (state, stats, drift, mod, t)
 
-    return jax.jit(
-        shard_map(jax.vmap(rewrite), mesh=mesh, in_specs=(spec,) * 4,
-                  out_specs=spec, check_rep=False),
-        donate_argnums=(3,))
+    return _lane_jit(rewrite, mesh, 4, donate_argnums=(3,))
 
 
 def _memory_analysis(step_fn, args) -> Dict[str, float] | None:
     """XLA memory analysis of a compiled chunk-step (peak/live byte sizes).
 
-    Best-effort: backends without `memory_analysis` return None."""
-    try:
-        ma = step_fn.lower(*args).compile().memory_analysis()
-        if ma is None:
-            return None
-        # The output carry is donated onto the input carry (aliased), so
-        # counting argument + output + temp would double-count the fleet
-        # state; peak live memory of a launch is arguments + temporaries.
-        return {
-            "argument_bytes": float(ma.argument_size_in_bytes),
-            "output_bytes": float(ma.output_size_in_bytes),
-            "temp_bytes": float(ma.temp_size_in_bytes),
-            "peak_bytes": float(ma.argument_size_in_bytes
-                                + ma.temp_size_in_bytes),
-        }
-    except Exception:  # pragma: no cover - backend-dependent surface
+    A host backend that reports no analysis gives None; on a TPU a missing
+    analysis raises, and so does any failure to lower or compile."""
+    ma = step_fn.lower(*args).compile().memory_analysis()
+    if ma is None:
+        if jax.default_backend() == "tpu":
+            raise RuntimeError("the TPU backend gave no memory analysis of "
+                               "the chunk-step program")
         return None
+    # The output carry is donated onto the input carry (aliased), so
+    # counting argument + output + temp would double-count the fleet
+    # state; peak live memory of a launch is arguments + temporaries.
+    return {
+        "argument_bytes": float(ma.argument_size_in_bytes),
+        "output_bytes": float(ma.output_size_in_bytes),
+        "temp_bytes": float(ma.temp_size_in_bytes),
+        "peak_bytes": float(ma.argument_size_in_bytes
+                            + ma.temp_size_in_bytes),
+    }
 
 
 def _policy_group_key(job: FleetJob):
@@ -548,9 +549,7 @@ def _policy_group_key(job: FleetJob):
     cfg = job.policy_config()
     return (cfg.use_regulator, cfg.load_balance, cfg.thresholded,
             cfg.pairing, cfg.threshold, cfg.fixed_node, cfg.wireless,
-            # interpret only matters when the pallas kernels actually run;
-            # keying it unconditionally would fork identical xla programs.
-            cfg.backend, cfg.interpret if cfg.backend == "pallas" else None)
+            cfg.backend)
 
 
 def run_fleet(jobs: Sequence[FleetJob], T: int, chunk: int = 1024,

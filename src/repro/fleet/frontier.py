@@ -422,7 +422,4 @@ def _probe_step_compiles(scenario, policy, eps_b, topo_seed, T, chunk,
                                 verdict=vcfg)
     mesh = Mesh(np.array(list(devices or jax.devices())), ("fleet",))
     _, step_fn, _ = make_group_launch(runner, mesh)
-    try:
-        return int(step_fn._cache_size())
-    except Exception:  # pragma: no cover - private API moved
-        return -1
+    return int(step_fn._cache_size())

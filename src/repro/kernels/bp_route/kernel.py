@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..platform import pallas_interpret
+
 
 def _bp_route_kernel(qm_ref, ql_ref, cap_ref, cls_ref, rate_ref, dir_ref):
     qm = qm_ref[...].astype(jnp.float32)       # [be, C]
@@ -29,7 +31,7 @@ def _bp_route_kernel(qm_ref, ql_ref, cap_ref, cls_ref, rate_ref, dir_ref):
 
 
 def bp_route_decide(qm: jax.Array, ql: jax.Array, cap: jax.Array, *,
-                    block_e: int = 256, interpret: bool = True):
+                    block_e: int = 256):
     """qm/ql: [E, C] backlogs at the two endpoints of each link; cap: [E].
 
     Returns (best_class [E] i32, rate [E] f32, direction [E] i32 with +1 =
@@ -63,6 +65,6 @@ def bp_route_decide(qm: jax.Array, ql: jax.Array, cap: jax.Array, *,
             jax.ShapeDtypeStruct((Ep,), jnp.float32),
             jax.ShapeDtypeStruct((Ep,), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(qm, ql, cap)
     return cls[:E], rate[:E], dirn[:E]

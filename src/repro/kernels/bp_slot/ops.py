@@ -20,10 +20,9 @@ from .ref import (balance_score, combine_amount, comp_balance_ref,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("block_e", "block_c", "interpret"))
+                   static_argnames=("block_e", "block_c"))
 def slot_route_op(Q: jax.Array, edges: jax.Array, cap: jax.Array, *,
-                  block_e: int = 128, block_c: int | None = None,
-                  interpret: bool = True):
+                  block_e: int = 128, block_c: int | None = None):
     """Q: [N, 3, NC] per-node class backlogs; edges: [E, 2]; cap: [E].
 
     Returns (best_class [E] i32 in 0..2, best_comp [E] i32, direction [E]
@@ -33,8 +32,7 @@ def slot_route_op(Q: jax.Array, edges: jax.Array, cap: jax.Array, *,
     NC = Q.shape[-1]
     Qf = Q.reshape(Q.shape[0], -1)
     best, dmax = slot_route_decide(Qf, edges[:, 0], edges[:, 1],
-                                   block_e=block_e, block_c=block_c,
-                                   interpret=interpret)
+                                   block_e=block_e, block_c=block_c)
     rate = jnp.where(jnp.abs(dmax) > 0, cap.astype(Qf.dtype), 0.0)
     dirn = jnp.where(dmax > 0, 1, -1).astype(jnp.int32)
     return best // NC, best % NC, dirn, rate

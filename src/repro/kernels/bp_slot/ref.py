@@ -5,7 +5,7 @@ on the `backend="xla"` path, and the Pallas kernels in `kernel.py` reuse the
 small algebra helpers (`pair_count`, `combine_amount`, `balance_score`)
 inside their kernel bodies — parity between the two backends is therefore
 *by construction*: identical f32 expressions evaluated on identical panels,
-so `slot_step(backend="pallas", interpret=True)` is bit-identical to
+so `slot_step(backend="pallas")` is bit-identical to
 `backend="xla"` (asserted by tests/test_bp_slot.py).
 
 Tie-break contract (DESIGN.md §7): both the routing argmax and the

@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..platform import pallas_interpret
+
 NEG = -1e30
 
 
@@ -41,7 +43,7 @@ def _bp_topk_kernel(s_ref, bias_ref, idx_ref, w_ref, *, k: int):
 
 
 def bp_topk(scores: jax.Array, bias: jax.Array, k: int, *,
-            block_t: int = 256, interpret: bool = True):
+            block_t: int = 256):
     """scores: [T, E] gate logits; bias: [E] (beta*H/C).  Returns
     (idx [T, k] i32, weights [T, k] f32, renormalized)."""
     T, E = scores.shape
@@ -67,6 +69,6 @@ def bp_topk(scores: jax.Array, bias: jax.Array, k: int, *,
             jax.ShapeDtypeStruct((Tp, k), jnp.int32),
             jax.ShapeDtypeStruct((Tp, k), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(scores, bias)
     return idx[:T], w[:T]

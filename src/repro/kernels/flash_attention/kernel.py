@@ -20,9 +20,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
+from ..platform import pallas_interpret
 
 NEG_INF = -1e30
 
@@ -73,12 +71,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int | None = None,
-                    block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    block_q: int = 128, block_k: int = 128) -> jax.Array:
     """q: [B, H, S, D]; k/v: [B, KH, T, D] (H % KH == 0).  Returns [B,H,S,D].
 
-    interpret=True runs the kernel body on CPU (this container); on real TPU
-    pass interpret=False.
+    The mode follows the platform (`repro.kernels.platform`): compiled on
+    a TPU, interpreted on the CPU.
     """
     B, H, S, D = q.shape
     KH, T = k.shape[1], k.shape[2]
@@ -121,8 +118,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(qf, kf, vf)
     return out.reshape(B, H, S, D)

@@ -7,6 +7,7 @@ never touches jax device state; the dry-run sets
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,7 +15,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     256 = 512 chips ("pod","data","model")."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh_for(n_devices: int, model_parallel: int = 1):
@@ -22,4 +23,5 @@ def make_mesh_for(n_devices: int, model_parallel: int = 1):
     are currently alive (used by the fault-recovery path)."""
     assert n_devices % model_parallel == 0
     return jax.make_mesh((n_devices // model_parallel, model_parallel),
-                         ("data", "model"))
+                         ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))

@@ -29,15 +29,14 @@ from typing import Callable, Dict, List, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh
 
 from repro.core.graph import ComputeProblem
 from repro.core.policies import PolicyConfig
 from repro.core.queues import VERDICT_NAMES
 from repro.fleet.batching import PadDims, pad_problem
-from repro.fleet.engine import (VerdictConfig, _policy_group_key,
-                                make_group_launch)
+from repro.fleet.engine import (VerdictConfig, _lane_jit,
+                                _policy_group_key, make_group_launch)
 from repro.fleet.scenarios import event_code, get_scenario
 from .admission import AdmissionConfig
 from .scheduler import make_serving_runner
@@ -59,14 +58,13 @@ class ServingJob:
     threshold: float = 0.0
     fixed_node: int = 0
     backend: str = "xla"          # slot-decision backend: "xla" | "pallas"
-    interpret: bool = True
 
     def policy_config(self) -> PolicyConfig:
         return PolicyConfig(
             name=self.policy, eps_b=self.eps_b, pairing=self.pairing,
             threshold=self.threshold, fixed_node=self.fixed_node,
             wireless=get_scenario(self.scenario).wireless,
-            backend=self.backend, interpret=self.interpret)
+            backend=self.backend)
 
 
 @dataclasses.dataclass
@@ -107,10 +105,7 @@ def _group_key(job: ServingJob):
 @functools.lru_cache(maxsize=64)
 def _probe_launch(runner, mesh: Mesh):
     """Jit the between-chunk probe readout (no donation — read-only)."""
-    spec = P("fleet")
-    return jax.jit(shard_map(jax.vmap(runner.probe), mesh=mesh,
-                             in_specs=(spec,), out_specs=spec,
-                             check_rep=False))
+    return _lane_jit(runner.probe, mesh, 1)
 
 
 def run_serving(jobs: Sequence[ServingJob], T: int, chunk: int = 512,

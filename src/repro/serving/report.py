@@ -26,7 +26,7 @@ def serving_report(scenario: str, policy: str, trace: str,
                    rate_fracs: Sequence[float], seeds: Sequence[int],
                    T: int, chunk: int = 512, window: int | None = None,
                    eps_b: float = 0.05, topo_seed: int = 0,
-                   backend: str = "xla", interpret: bool = True,
+                   backend: str = "xla",
                    devices=None, verdict: VerdictConfig | None = None,
                    admission: AdmissionConfig | None = None,
                    stream: bool = False) -> dict:
@@ -41,7 +41,7 @@ def serving_report(scenario: str, policy: str, trace: str,
     bound = policy_bound_exact(scenario, policy, eps_b, topo_seed)
     jobs = [ServingJob(scenario=scenario, policy=policy, trace=trace,
                        lam=frac * bound, seed=seed, topo_seed=topo_seed,
-                       eps_b=eps_b, backend=backend, interpret=interpret)
+                       eps_b=eps_b, backend=backend)
             for frac in rate_fracs for seed in seeds]
     res = run_serving(jobs, T, chunk=chunk, window=window, devices=devices,
                       verdict=verdict, admission=admission, stream=stream)

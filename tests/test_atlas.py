@@ -262,7 +262,8 @@ class TestAtlasEquivalence:
         assert set(tbl["families"]) == {c.scenario for c in MINI_CELLS}
         for fam in tbl["families"].values():
             assert fam["n_cells"] == 1
-            assert 0.0 <= fam["ratio_median"] <= 1.0
+            # k * (rel_tol * bound) / bound can round one ulp past 1.0
+            assert 0.0 <= fam["ratio_median"] <= 1.0 + 1e-9
             cell = fam["cells"][0]
             assert {"lam_max", "bound_exact", "undecided_hi",
                     "hi_certain"} <= set(cell)
@@ -325,15 +326,17 @@ class TestMixedRateEarlyStopRegression:
         horizon.  The undecided sim's metrics must be bit-equal to an
         early_stop=False run — deciders freezing around it must not
         perturb its lanes."""
+        # chunk 512 keeps the verdict burn-in past the gradient-fill
+        # transient; T = 8 chunks leaves the deciders room to latch early.
         jobs = [FleetJob(scenario="paper_grid", policy="pi3", lam=lam,
                          eps_b=0.05, seed=0) for lam in (4.0, 8.2, 8.8)]
-        a = run_fleet(jobs, T=2048, chunk=256, early_stop=True)
-        b = run_fleet(jobs, T=2048, chunk=256, early_stop=False)
+        a = run_fleet(jobs, T=4096, chunk=512, early_stop=True)
+        b = run_fleet(jobs, T=4096, chunk=512, early_stop=False)
         va, vb = a.verdicts(), b.verdicts()
         assert va == vb == ["STABLE", "UNDECIDED", "UNSTABLE"]
         # the mix is real: both deciders latched before the horizon
-        assert a.metrics[0]["decided_at_slot"] < 2048
-        assert a.metrics[2]["decided_at_slot"] < 2048
+        assert a.metrics[0]["decided_at_slot"] < 4096
+        assert a.metrics[2]["decided_at_slot"] < 4096
         # ... and the undecided sim is bit-untouched by their freezing
         mu_a, mu_b = dict(a.metrics[1]), dict(b.metrics[1])
         mu_a.pop("slots_saved"), mu_b.pop("slots_saved")
@@ -516,14 +519,14 @@ class TestAdaptiveRequeue:
         UNDECIDED) must also burn the re-queue ladder.  At rates far
         below capacity the backpressure gradient fills so slowly that
         the whole horizon sits inside the transient and the drift + gap
-        tests latch a *false* UNSTABLE — paper_grid topo_seed 8 / seed 1
+        tests latch a *false* UNSTABLE — paper_grid topo_seed 8 / seed 0
         at T=4096 reads proven-UNSTABLE at 0.1x its own exact bound and
         collapses with certainty (hi_certain populated, not UNDECIDED).
         One 2xT rung must repair it: the fresh attempt's top-of-bracket
         probe decides STABLE on the longer run and the search ascends
         to the true bound instead of reporting 0."""
         cells = [AtlasJob("paper_grid", topo_seed=8, eps_b=0.05)]
-        kw = dict(seeds=(1,), T=4096, chunk=512, rel_tol=0.1, max_calls=8)
+        kw = dict(seeds=(0,), T=4096, chunk=512, rel_tol=0.1, max_calls=8)
         base = sweep_lambda_max(cells, **kw).rows[0]
         # the bug: a false-certain collapse — no UNDECIDED escape hatch
         assert base.lam_max == 0.0 and not base.undecided

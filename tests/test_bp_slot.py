@@ -169,7 +169,7 @@ SCEN_NAMES = ("paper_grid", "ring", "fat_tree")
 
 def _check_slot_step_parity(scen, policy, pad_extra, eps_b, fail_pattern,
                             seed):
-    """`slot_step(backend="pallas", interpret=True)` must equal
+    """`slot_step(backend="pallas")` (the interpreter, on the CPU) must equal
     `backend="xla"` bit-exactly on padded problems with failed comp nodes
     and a traced eps_B — every state leaf, every metric, every slot."""
     problem = get_scenario(scen).build(0)
@@ -263,3 +263,17 @@ def test_slot_step_parity_regulated_jitted_scan():
 def test_backend_validation():
     with pytest.raises(ValueError, match="unknown backend"):
         PolicyConfig(name="pi3", backend="cuda")
+
+
+@pytest.mark.parametrize("platform,expected", [("cpu", True), ("tpu", False),
+                                               ("gpu", None)])
+def test_kernel_mode_follows_the_platform(platform, expected, monkeypatch):
+    """Interpreted on the CPU, compiled on a TPU, refused elsewhere."""
+    from repro.kernels import platform as kp
+
+    monkeypatch.setattr(kp, "default_backend", lambda: platform)
+    if expected is None:
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            kp.pallas_interpret()
+    else:
+        assert kp.pallas_interpret() is expected

@@ -807,3 +807,26 @@ class TestCompensatedCounters:
         for _ in range(10):
             naive = naive + jnp.float32(0.25)
         assert float(naive) == 2.0 ** 24
+
+
+class _NoAnalysis:
+    """Stands in for a jitted step whose compiled program reports no
+    memory analysis."""
+
+    def lower(self, *args):
+        return self
+
+    def compile(self):
+        return self
+
+    def memory_analysis(self):
+        return None
+
+
+def test_memory_analysis_missing_on_tpu_raises(monkeypatch):
+    from repro.fleet import engine
+
+    assert engine._memory_analysis(_NoAnalysis(), ()) is None  # host backend
+    monkeypatch.setattr(engine.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="no memory analysis"):
+        engine._memory_analysis(_NoAnalysis(), ())

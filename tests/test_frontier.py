@@ -270,9 +270,12 @@ class TestSeedDecoupling:
 @pytest.mark.fleet_smoke
 class TestFleetVerdictMetrics:
     def test_rows_gain_verdict_fields(self):
+        # Verdict discipline (DESIGN.md §8): chunk >= 512 keeps the burn-in
+        # past the gradient-fill transient, and T = 8 chunks leaves room
+        # for the 3 stable windows after it.
         jobs = [FleetJob(scenario="paper_grid", policy="pi3", lam=lam,
                          eps_b=0.05, seed=0) for lam in (2.0, 14.0)]
-        res = run_fleet(jobs, T=2048, chunk=256)      # early_stop off
+        res = run_fleet(jobs, T=4096, chunk=512)      # early_stop off
         for m in res.metrics:
             assert {"verdict", "decided_at_slot", "slots_saved"} <= set(m)
             assert m["slots_saved"] == 0.0            # no freezing

@@ -281,7 +281,8 @@ class TestServingEngine:
         bound = policy_bound_exact("paper_grid", "pi3_reg", 0.05, 0)
         jobs = [ServingJob(trace=tr, lam=0.6 * bound, seed=s)
                 for tr in ("steady", "bursty") for s in (0, 1)]
-        res = run_serving(jobs, T=1024, chunk=256)
+        # admission windows of 512 slots, past the gradient-fill transient
+        res = run_serving(jobs, T=4096, chunk=512)
         assert res.n_sims == 4
         assert res.n_programs == 2          # one program per (policy, trace)
         np.testing.assert_array_equal(res.column("shed_frac"), 0.0)
@@ -346,14 +347,14 @@ class TestServingEngine:
 
     def test_report_and_stream_jsonl(self, tmp_path):
         rep = serving_report("paper_grid", "pi3_reg", "bursty",
-                             rate_fracs=(0.6,), seeds=(0,), T=512,
-                             chunk=128, stream=True)
+                             rate_fracs=(0.6,), seeds=(0,), T=4096,
+                             chunk=512, stream=True)
         row = rep["rows"]["0.6"]
         assert row["shed_frac"] == 0.0
         assert row["delivered_over_bound"] >= 0.5
         assert rep["bound_exact"] > 0
         res = rep["result"]
-        assert len(res.stream_records) == res.T // 128
+        assert len(res.stream_records) == res.T // 512
         path = tmp_path / "stream.jsonl"
         n = write_stream_jsonl(res, str(path))
         lines = path.read_text().splitlines()

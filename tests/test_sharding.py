@@ -65,7 +65,8 @@ MINI_DRYRUN = textwrap.dedent("""
     cfg = reduced(get_config("qwen2-0.5b"), d_model=64, n_heads=4,
                   n_kv_heads=2, head_dim=16, d_ff=128, vocab=256)
     rcfg = RunConfig(model=cfg, shape=ShapeConfig("t", 64, 8, "train"))
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh_for
+    mesh = make_mesh_for(8, model_parallel=2)          # (4, 2), Auto axes
     rules = shd.make_rules(mesh, fsdp=True, expert_parallel=True)
     with shd.use_rules(rules):
         state, axes = init_train_state(rcfg, abstract=True)

@@ -1,0 +1,147 @@
+"""Compile the chip's main path for a described TPU v5e, without a chip.
+
+The TPU compiler is installed next to JAX, and it compiles for a topology
+that is described rather than attached: what it refuses here (tile
+alignment, unsupported kernel ops, memory) would be refused on the chip.
+This file compiles, with the kernels' platform rule steered to the
+chip's choice (compiled, not interpreted):
+
+  * both `bp_slot` kernels at the atlas bucket hull (N=16, E=51, NC=4),
+    alone and vmapped over the engine's lanes, and at a deployment-size
+    shape with E >= 1,024;
+  * the whole fleet chunk step for backend="xla" and backend="pallas" on
+    one chip (the pallas step must carry the kernels as
+    `tpu_custom_call`s);
+  * the xla chunk step on a four-chip lane mesh.
+
+Nothing runs, so nothing here is a result or a time.  The topology is
+described inside a module fixture (never at import: only one process
+may hold the TPU library), and the persistent compile cache is off
+around these compiles, since entries written for a described chip cannot
+be read back without one.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from repro.core import PolicyConfig
+from repro.fleet import PadDims, get_scenario, pad_problem
+from repro.fleet.engine import (DEFAULT_VERDICT, _make_stream_runner,
+                                make_group_launch)
+from repro.kernels import platform
+from repro.kernels.bp_slot.kernel import comp_balance_decide, slot_route_decide
+
+#: (N, E, NC): the atlas bucket hull, and a fat-tree-scale shape.
+KERNEL_SHAPES = [(16, 51, 4), (208, 1536, 16)]
+LANES = 504                       # the atlas's largest bucket, in lanes
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        from jax.experimental import topologies
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def chip_kernels(monkeypatch):
+    """The kernels resolve their mode from the default backend, which is
+    the CPU here; steer it to the chip's choice for these traces."""
+    monkeypatch.setattr(platform, "default_backend", lambda: "tpu")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return Mesh(np.array(topo.devices[:1]), ("fleet",))
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    return Mesh(np.array(topo.devices[:4]), ("fleet",))
+
+
+def _sds(shape, dtype, mesh, spec=P()):
+    return jax.ShapeDtypeStruct(shape, dtype,
+                                sharding=NamedSharding(mesh, spec))
+
+
+def _kernel_args(mesh, N, E, NC, lanes):
+    lead = (lanes,) if lanes else ()
+    spec = P("fleet") if lanes else P()
+    f32 = lambda *s: _sds(lead + s, jnp.float32, mesh, spec)
+    i32 = lambda *s: _sds(lead + s, jnp.int32, mesh, spec)
+    route = (f32(N, 3 * NC), i32(E), i32(E))
+    comp = (f32(),) + tuple(f32(NC) for _ in range(12))
+    return route, comp
+
+
+@pytest.mark.parametrize("lanes", [0, LANES], ids=["one", "vmapped"])
+@pytest.mark.parametrize("N,E,NC", KERNEL_SHAPES,
+                         ids=lambda v: str(v))
+def test_bp_slot_kernels_compile(one_chip, chip_kernels, N, E, NC, lanes):
+    route_args, comp_args = _kernel_args(one_chip, N, E, NC, lanes)
+    route, comp = slot_route_decide, comp_balance_decide
+    if lanes:
+        route, comp = jax.vmap(route), jax.vmap(comp)
+    for fn, args in ((route, route_args), (comp, comp_args)):
+        compiled = jax.jit(fn).lower(*args).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+
+
+def _compile_chunk_step(mesh, backend):
+    """Lower and compile the fleet's donated chunk step for `mesh`: the
+    atlas's pi3 group at the pad dims of paper_grid/expander/fat_tree,
+    T=4096, chunk=512.  Fresh (un-memoized) runner and launch objects,
+    so no trace made on the CPU is reused."""
+    problems = [get_scenario(s).build(0)
+                for s in ("paper_grid", "expander", "fat_tree")]
+    dims = PadDims.of(problems)
+    pads = [pad_problem(problems[i % len(problems)], dims)
+            for i in range(LANES)]
+    pp = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *pads)
+    cfg = PolicyConfig(name="pi3", backend=backend)
+    runner = _make_stream_runner.__wrapped__(cfg, 4096, 512, None,
+                                             DEFAULT_VERDICT)
+    _, step_fn, _ = make_group_launch.__wrapped__(runner, mesh)
+    lane = jax.ShapeDtypeStruct((LANES,), jnp.float32)
+    code = jax.ShapeDtypeStruct((LANES,), jnp.int32)
+    keys = jax.eval_shape(jax.vmap(jax.random.PRNGKey), code)
+    carry = jax.eval_shape(jax.vmap(runner.init_carry), pp)
+    shard = NamedSharding(mesh, P("fleet"))
+    args = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=shard),
+        (pp, lane, lane, code, code, keys, carry))
+    return step_fn.lower(*args).compile()
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_fleet_chunk_step_compiles_one_chip(one_chip, chip_kernels, backend):
+    compiled = _compile_chunk_step(one_chip, backend)
+    hlo = compiled.as_text()
+    assert ("tpu_custom_call" in hlo) == (backend == "pallas")
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 16e9
+
+
+def test_fleet_chunk_step_compiles_four_chip_mesh(four_chips):
+    compiled = _compile_chunk_step(four_chips, "xla")
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes > 0
