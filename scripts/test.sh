@@ -160,11 +160,10 @@ print(f"atlas_smoke: {res.n_cells} cells in {res.n_launches} launches "
 
 # Bucketed re-run (DESIGN.md §13): 2 shape buckets must stay <= 2
 # compiled programs per (policy group x bucket), with a per-bucket launch
-# ledger that sums to the total.  n_step_compiles reads the absolute jit
-# cache size (so resume bit-equality holds, test_resilience), and the
-# single-bucket run above already warmed the hull-shape traces — the
-# no-retrace invariant here is the *delta*: at most one new trace per
-# (group x bucket) program.
+# ledger that sums to the total.  n_step_compiles counts one program a
+# launch unit plus every retrace inside a unit's launch loop, so the
+# no-retrace invariant holds whatever the single-bucket run above left in
+# the jit caches.
 res2 = sweep_lambda_max(cells, seeds=(0,), T=512, chunk=256,
                         rel_tol=0.1, max_calls=6, n_buckets=2)
 assert res2.n_buckets == 2, res2.n_buckets

@@ -165,8 +165,9 @@ def bp_route_slot(sp: StaticProblem, state: NetState,
         alloc = alloc * emask
         weight = weight * emask
     if wireless:
-        active = greedy_maximal_matching(jnp.asarray(sp.edges),
-                                         weight, sp.n_nodes)
+        with jax.named_scope("matching"):
+            active = greedy_maximal_matching(jnp.asarray(sp.edges),
+                                             weight, sp.n_nodes)
         alloc = alloc * active
     src = jnp.where(dmax > 0, m_idx, l_idx)
     dst = jnp.where(dmax > 0, l_idx, m_idx)
@@ -369,11 +370,17 @@ def slot_step(sp: StaticProblem, cfg: PolicyConfig, state: NetState,
 
     `cfg.backend` selects the decision implementation — "xla" (the pure-jnp
     oracle in `repro.kernels.bp_slot.ref`) or "pallas" (the fused tiled
-    kernels, bit-identical; DESIGN.md §7)."""
-    state, assigned, m1 = load_balance_slot(sp, cfg, state, arrivals, eps_b)
-    state, m2 = bp_route_slot(sp, state, wireless=cfg.wireless,
-                              backend=cfg.backend)
-    state, m3 = computation_slot(sp, cfg, state, assigned, key, eps_b)
+    kernels, bit-identical; DESIGN.md §7).  Each sub-step runs under its
+    named scope (`repro.obs.spans.SLOT_SCOPES`), which names its ops in a
+    device trace and changes nothing else."""
+    with jax.named_scope("balance"):
+        state, assigned, m1 = load_balance_slot(sp, cfg, state, arrivals,
+                                                eps_b)
+    with jax.named_scope("route"):
+        state, m2 = bp_route_slot(sp, state, wireless=cfg.wireless,
+                                  backend=cfg.backend)
+    with jax.named_scope("compute"):
+        state, m3 = computation_slot(sp, cfg, state, assigned, key, eps_b)
     metrics = {
         "total_queue": state.total_queue(),
         "delivered": state.delivered,

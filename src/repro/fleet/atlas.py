@@ -50,6 +50,7 @@ from jax.sharding import Mesh
 
 from repro.core.graph import ComputeProblem
 from repro.core.queues import VERDICT_NAMES, VERDICT_UNDECIDED
+from repro.obs.spans import span, spanned
 from .batching import PadDims, make_buckets, pad_problem
 from .engine import (FleetJob, VerdictConfig, _policy_group_key,
                      make_group_launch, make_sim_rewriter,
@@ -117,11 +118,11 @@ class AtlasResult:
     n_launches: int          # chunk-step launches the atlas dispatched
     seq_launches: int        # launches per-cell find_lambda_max would issue
     n_rewrites: int          # in-place carry rewrites at launch boundaries
-    n_step_compiles: int     # summed step-trace cache sizes (== n_programs
-                             # in a cold process; warm memoized caches from
-                             # an earlier same-process sweep count too, so
-                             # resume bit-equality holds — compare deltas
-                             # across back-to-back sweeps, not absolutes)
+    n_step_compiles: int     # step programs the launch units ran: one a
+                             # unit that launched, plus each retrace inside
+                             # its launch loop (== n_programs unless the
+                             # loop retraced; the same in a warm process
+                             # and across a resume)
     total_slots: int
     full_slots: int
     slots_saved: int
@@ -173,6 +174,7 @@ def registry_cells(families: Sequence[str], topo_seeds: Sequence[int],
             for f in families for ts in topo_seeds]
 
 
+@spanned("atlas.sweep")
 def sweep_lambda_max(cells: Sequence[AtlasJob], *,
                      seeds: Sequence[int] = (0,), T: int = 4096,
                      chunk: int = 512, window: int | None = None,
@@ -238,6 +240,13 @@ def sweep_lambda_max(cells: Sequence[AtlasJob], *,
     Host dropouts park the affected cells' lanes and finish their rows
     from the current bracket with ``degraded=True`` (reported in
     ``AtlasResult.degraded``) while the rest of the atlas keeps bisecting.
+
+    With `repro.obs.spans` recording, the call is one ``atlas.sweep``
+    span.  Per launch unit, ``atlas.unit_setup`` (``n``: padded lanes);
+    per launch, ``atlas.launch`` (``n``: lanes of active cells),
+    ``atlas.readback``, ``atlas.harvest`` (``n``: probes harvested),
+    ``atlas.rewrite`` (``n``: lanes reset or parked), ``atlas.record`` and
+    ``atlas.snapshot`` where they run.
     """
     cells = list(cells)
     if not cells:
@@ -308,14 +317,13 @@ def sweep_lambda_max(cells: Sequence[AtlasJob], *,
                                          policy=c.policy, eps_b=c.eps_b,
                                          topo_seed=c.topo_seed))
         groups.setdefault(key, []).append(ci)
-    units: List[Tuple[tuple, int, List[int], bool]] = []
+    units: List[Tuple[tuple, int, List[int]]] = []
     for gkey, cidx_g in groups.items():
         by_bucket: Dict[int, List[int]] = {}
         for ci in cidx_g:
             by_bucket.setdefault(cell_bucket[ci], []).append(ci)
-        bs = sorted(by_bucket)
-        for b in bs:
-            units.append((gkey, b, by_bucket[b], b == bs[-1]))
+        for b in sorted(by_bucket):
+            units.append((gkey, b, by_bucket[b]))
 
     rt = resumed = None
     if resilience is not None:
@@ -366,7 +374,7 @@ def sweep_lambda_max(cells: Sequence[AtlasJob], *,
         degraded = {int(k): v for k, v in resumed["degraded"].items()}
         recovery = rz.plan_restore(resumed["recovery"])
 
-    for g, (gkey, bkt, cidx, group_last) in enumerate(units):
+    for g, (gkey, bkt, cidx) in enumerate(units):
         cfg = FleetJob(scenario=cells[cidx[0]].scenario,
                        policy=cells[cidx[0]].policy,
                        eps_b=cells[cidx[0]].eps_b,
@@ -378,109 +386,122 @@ def sweep_lambda_max(cells: Sequence[AtlasJob], *,
         if resumed is not None and g < resumed["group"]:
             continue              # finished pre-kill: rows restored above
 
-        # Lane layout: S contiguous lanes per cell, mesh-padded by
-        # repeating the last real lane (run_fleet's replica convention —
-        # replicas mirror every rewrite of their source cell and are never
-        # harvested).
-        lane_cells = [ci for ci in cidx for _ in seeds]
-        B = len(lane_cells)
-        Bp = -(-B // ndev) * ndev
-        lane_pad = lane_cells + [lane_cells[-1]] * (Bp - B)
-        lane_of = {ci: slice(j * S, (j + 1) * S)
-                   for j, ci in enumerate(cidx)}
+        with span("atlas.unit_setup") as sp:
+            # Lane layout: S contiguous lanes per cell, mesh-padded by
+            # repeating the last real lane (run_fleet's replica convention
+            # — replicas mirror every rewrite of their source cell and are
+            # never harvested).
+            lane_cells = [ci for ci in cidx for _ in seeds]
+            B = len(lane_cells)
+            Bp = -(-B // ndev) * ndev
+            lane_pad = lane_cells + [lane_cells[-1]] * (Bp - B)
+            sp.n = Bp
+            lane_of = {ci: slice(j * S, (j + 1) * S)
+                       for j, ci in enumerate(cidx)}
 
-        pp = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs),
-            *[padded_of[(cells[ci].scenario, cells[ci].topo_seed)]
-              for ci in lane_pad])
-        eps = jnp.array([cells[ci].eps_b for ci in lane_pad], jnp.float32)
-        ak = jnp.array([arrival_code(get_scenario(cells[ci].scenario).arrival)
-                        for ci in lane_pad], jnp.int32)
-        ek = jnp.array([event_code(get_scenario(cells[ci].scenario).events)
-                        for ci in lane_pad], jnp.int32)
+            pp = jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs),
+                *[padded_of[(cells[ci].scenario, cells[ci].topo_seed)]
+                  for ci in lane_pad])
+            eps = jnp.array([cells[ci].eps_b for ci in lane_pad],
+                            jnp.float32)
+            ak = jnp.array([arrival_code(
+                get_scenario(cells[ci].scenario).arrival)
+                for ci in lane_pad], jnp.int32)
+            ek = jnp.array([event_code(
+                get_scenario(cells[ci].scenario).events)
+                for ci in lane_pad], jnp.int32)
 
-        init_fn, step_fn, _ = make_group_launch(runner, mesh)
-        rewrite_fn = make_sim_rewriter(runner, mesh)
+            init_fn, step_fn, _ = make_group_launch(runner, mesh)
+            rewrite_fn = make_sim_rewriter(runner, mesh)
 
-        # Host-side scheduler state: each active cell's pending grid index
-        # and how many chunk launches its current probe has consumed.
-        pending: Dict[int, int] = {}
-        chunks_used: Dict[int, int] = {}
-        probes_of: Dict[int, List[RateProbe]] = {ci: [] for ci in cidx}
-        lam_host = np.zeros(Bp, np.float32)
-        seed_host = np.zeros(Bp, np.int32)
-        active: set = set()
+            # Host-side scheduler state: each active cell's pending grid
+            # index and how many chunk launches its current probe has
+            # consumed.
+            pending: Dict[int, int] = {}
+            chunks_used: Dict[int, int] = {}
+            probes_of: Dict[int, List[RateProbe]] = {ci: [] for ci in cidx}
+            lam_host = np.zeros(Bp, np.float32)
+            seed_host = np.zeros(Bp, np.int32)
+            active: set = set()
 
-        def _assign(ci: int, k: int) -> None:
-            # call_index = attempt: first-attempt probes replay the exact
-            # sequential fold_seed stream (call_index 0); adaptive re-probes
-            # draw from the documented re-probe stream so doubled-horizon
-            # evidence never aliases the evidence that failed to decide.
-            pending[ci] = k
-            chunks_used[ci] = 0
-            sl = lane_of[ci]
-            lam_host[sl] = np.float32(k * steps[ci])
-            seed_host[sl] = [fold_seed(cells[ci].topo_seed, k,
-                                       attempt[ci], s)
-                             for s in seeds]
+            def _assign(ci: int, k: int) -> None:
+                # call_index = attempt: first-attempt probes replay the
+                # exact sequential fold_seed stream (call_index 0); adaptive
+                # re-probes draw from the documented re-probe stream so
+                # doubled-horizon evidence never aliases the evidence that
+                # failed to decide.
+                pending[ci] = k
+                chunks_used[ci] = 0
+                sl = lane_of[ci]
+                lam_host[sl] = np.float32(k * steps[ci])
+                seed_host[sl] = [fold_seed(cells[ci].topo_seed, k,
+                                           attempt[ci], s)
+                                 for s in seeds]
 
-        resume_here = resumed is not None and g == resumed["group"]
-        if resume_here and resumed["g_launches"] > 0:
-            # Mid-group restore: the carry at the snapshot boundary plus
-            # the lane tables / pending probes exactly as the killed sweep
-            # left them; machines/rows/counters were restored above.
-            pending = {int(k): v for k, v in resumed["pending"].items()}
-            chunks_used = {int(k): v
-                           for k, v in resumed["chunks_used"].items()}
-            for ci_s, ps in resumed["probes"].items():
-                probes_of[int(ci_s)] = [rz.probe_restore(p) for p in ps]
-            lam_host = np.array(resumed["lam_host"], np.float32)
-            seed_host = np.array(resumed["seed_host"], np.int32)
-            active = set(resumed["active"])
-            like = jax.eval_shape(init_fn, pp)
-            carry = rt.restore_carry(like, mesh)
-            g_launches = resumed["g_launches"]
-        else:
-            carry = init_fn(pp)
-            park0 = np.zeros(Bp, bool)
-            for ci in cidx:
-                k = machines[ci].next_rate_index()
-                if k is None:       # degenerate budget: decided probe-free
-                    rows[ci] = _finish_row(cells[ci], bounds[ci], steps[ci],
-                                           machines[ci], [], bucket=bkt)
-                    park0[lane_of[ci]] = True
-                else:
-                    active.add(ci)
-                    _assign(ci, k)
-            lam_host[B:] = lam_host[B - 1]
-            seed_host[B:] = seed_host[B - 1]
-            park0[B:] = park0[B - 1]
-            if park0.any():
-                carry = rewrite_fn(pp, jnp.zeros(Bp, bool),
-                                   jnp.asarray(park0), carry)
-                n_rewrites += 1
-            g_launches = 0
-        if sink is not None and resume_here:
-            from repro.obs import schema
-            sink.write(schema.make_record(
-                "resume", group=g, chunk=g_launches,
-                t=g_launches * runner.chunk, n_sims=B, engine="atlas",
-                ckpt_step=resumed["ckpt_step"],
-                n_preloaded=sink.n_preloaded))
-
-        while active:
-            lam = jnp.asarray(lam_host)
-            keys = jax.vmap(jax.random.PRNGKey)(jnp.asarray(seed_host))
-            if rt is not None:
-                try:
-                    carry = rt.launch(g, n_launches, step_fn, pp, lam, eps,
-                                      ak, ek, keys, carry)
-                except Exception:
-                    if sink is not None:
-                        sink.close()
-                    raise
+            resume_here = resumed is not None and g == resumed["group"]
+            if resume_here and resumed["g_launches"] > 0:
+                # Mid-group restore: the carry at the snapshot boundary
+                # plus the lane tables / pending probes exactly as the
+                # killed sweep left them; machines/rows/counters were
+                # restored above.
+                pending = {int(k): v for k, v in resumed["pending"].items()}
+                chunks_used = {int(k): v
+                               for k, v in resumed["chunks_used"].items()}
+                for ci_s, ps in resumed["probes"].items():
+                    probes_of[int(ci_s)] = [rz.probe_restore(p) for p in ps]
+                lam_host = np.array(resumed["lam_host"], np.float32)
+                seed_host = np.array(resumed["seed_host"], np.int32)
+                active = set(resumed["active"])
+                like = jax.eval_shape(init_fn, pp)
+                carry = rt.restore_carry(like, mesh)
+                g_launches = resumed["g_launches"]
             else:
-                carry = step_fn(pp, lam, eps, ak, ek, keys, carry)
+                carry = init_fn(pp)
+                park0 = np.zeros(Bp, bool)
+                for ci in cidx:
+                    k = machines[ci].next_rate_index()
+                    if k is None:   # degenerate budget: decided probe-free
+                        rows[ci] = _finish_row(cells[ci], bounds[ci],
+                                               steps[ci], machines[ci], [],
+                                               bucket=bkt)
+                        park0[lane_of[ci]] = True
+                    else:
+                        active.add(ci)
+                        _assign(ci, k)
+                lam_host[B:] = lam_host[B - 1]
+                seed_host[B:] = seed_host[B - 1]
+                park0[B:] = park0[B - 1]
+                if park0.any():
+                    carry = rewrite_fn(pp, jnp.zeros(Bp, bool),
+                                       jnp.asarray(park0), carry)
+                    n_rewrites += 1
+                g_launches = 0
+            if sink is not None and resume_here:
+                from repro.obs import schema
+                sink.write(schema.make_record(
+                    "resume", group=g, chunk=g_launches,
+                    t=g_launches * runner.chunk, n_sims=B, engine="atlas",
+                    ckpt_step=resumed["ckpt_step"],
+                    n_preloaded=sink.n_preloaded))
+
+        first_cache = None        # the step's jit-cache size after launch 1
+        while active:
+            with span("atlas.launch", n=len(active) * S):
+                lam = jnp.asarray(lam_host)
+                keys = jax.vmap(jax.random.PRNGKey)(jnp.asarray(seed_host))
+                if rt is not None:
+                    try:
+                        carry = rt.launch(g, n_launches, step_fn, pp, lam,
+                                          eps, ak, ek, keys, carry)
+                    except Exception:
+                        if sink is not None:
+                            sink.close()
+                        raise
+                else:
+                    carry = step_fn(pp, lam, eps, ak, ek, keys, carry)
+            if first_cache is None:
+                first_cache = int(step_fn._cache_size())
             n_launches += 1
             g_launches += 1
             bucket_launches[bkt] += 1
@@ -488,138 +509,151 @@ def sweep_lambda_max(cells: Sequence[AtlasJob], *,
                 chunks_used[ci] += 1
 
             # Between-launch readout: the two [Bp] drift leaves only.
-            v_leaf, d_leaf = runner.drift_of(carry)
-            verdicts = np.asarray(jax.device_get(v_leaf))
-            decided_at = np.asarray(jax.device_get(d_leaf))
+            with span("atlas.readback"):
+                v_leaf, d_leaf = runner.drift_of(carry)
+                verdicts = np.asarray(jax.device_get(v_leaf))
+                decided_at = np.asarray(jax.device_get(d_leaf))
 
-            reset = np.zeros(Bp, bool)
-            park = np.zeros(Bp, bool)
-            changed = False
-            if rt is not None:
-                dead = rt.dead_hosts(n_launches)
-                if dead:
-                    # Graceful degradation: park every active cell with a
-                    # lane on a dead host, finish its row from the bracket
-                    # *at the dropout* (degraded=True, never silent), and
-                    # re-plan the mesh.  The rest of the atlas keeps
-                    # bisecting.
-                    lane_dead = rz.host_lane_mask(Bp, ndev, dead)
-                    per = Bp // ndev
-                    for ci in sorted(active):
-                        sl = lane_of[ci]
-                        if lane_dead[sl].any():
-                            active.discard(ci)
-                            park[sl] = True
-                            rows[ci] = _finish_row(
-                                cells[ci], bounds[ci], steps[ci],
-                                machines[ci], probes_of[ci], degraded=True,
-                                bucket=bkt, n_requeues=attempt[ci])
-                            hosts = sorted({l // per
-                                            for l in range(sl.start, sl.stop)
-                                            if lane_dead[l]})
-                            degraded[ci] = "host_dropout:" + ",".join(
-                                f"host{h}" for h in hosts)
-                            changed = True
-                    if recovery is None or set(dead) != set(recovery.evict):
-                        from repro.runtime.fault import plan_recovery
-                        recovery = plan_recovery(
-                            ndev, 1, [f"host{h}" for h in dead], [], 1)
-            for ci in sorted(active):
-                sl = lane_of[ci]
-                v = verdicts[sl]
-                # Adaptive horizon: attempt a probes up to n_chunks << a
-                # launches — verdict latching lives in the window config,
-                # not T, so a doubled horizon is just more chunk launches
-                # of the same program.
-                horizon = n_chunks << attempt[ci]
-                finished = chunks_used[ci] >= horizon or (
-                    early_stop and bool(np.all(v != VERDICT_UNDECIDED)))
-                if not finished:
-                    continue
-                # --- harvest: the exact RateProbe the sequential path
-                # would have built from run_fleet's finalize metrics.
-                k = pending[ci]
-                cell_T = runner.T << attempt[ci]
-                names = tuple(VERDICT_NAMES[int(x)] for x in v)
-                sustainable = all(n == "STABLE" for n in names)
-                d_eff = np.where(v != VERDICT_UNDECIDED,
-                                 decided_at[sl], cell_T)
-                saved = (int(np.sum(cell_T - d_eff)) if vcfg.freeze
-                         else 0)
-                probes_of[ci].append(RateProbe(
-                    rate_index=k, call_index=attempt[ci],
-                    lam=k * steps[ci],
-                    sustainable=sustainable, verdicts=names,
-                    decided_at=tuple(int(x) for x in d_eff),
-                    slots_run=S * cell_T - saved, slots_saved=saved,
-                    undecided=not sustainable and "UNSTABLE" not in names))
-                seq_launches += chunks_used[ci]
-                launch_slots_saved += \
-                    S * (horizon - chunks_used[ci]) * runner.chunk
-                machines[ci].record(k, sustainable,
-                                    probes_of[ci][-1].undecided)
-                k2 = machines[ci].next_rate_index()
-                if k2 is None and (machines[ci].undecided_hi
-                                   or machines[ci].k_lo == 0) \
-                        and attempt[ci] < max_requeues:
-                    # Re-queue (DESIGN.md §13): either the bracket top is
-                    # blocked by UNDECIDED-at-horizon evidence only, or
-                    # the bracket fully collapsed (k_lo == 0: no rate
-                    # proved sustainable).  The collapse case covers the
-                    # low-rate false-UNSTABLE artifact — at rates far
-                    # below capacity the backpressure gradient fills so
-                    # slowly that the whole horizon sits inside the
-                    # transient and the drift + gap tests both latch
-                    # UNSTABLE (paper_grid topo_seeds 5/8/15 at T=4096
-                    # read proven-UNSTABLE at 0.1x their own exact
-                    # bound); genuinely-capacity-0 cells (wireless_grid)
-                    # burn the re-queue ladder and still report 0, which
-                    # the bench asserts.  Restart the whole search from
-                    # the original integer bracket with a doubled chunk
-                    # budget instead of reporting the collapsed bracket.
-                    # The fresh machine replays the deterministic probe
-                    # order; _assign stamps the bumped call_index into
-                    # every fold_seed.
-                    attempt[ci] += 1
-                    n_requeues += 1
-                    machines[ci] = Bisection(k_lo=k_lo0[ci], k_hi=k_hi0[ci],
-                                             max_calls=max_calls)
+            with span("atlas.harvest") as sp:
+                harvested = 0
+                reset = np.zeros(Bp, bool)
+                park = np.zeros(Bp, bool)
+                changed = False
+                if rt is not None:
+                    dead = rt.dead_hosts(n_launches)
+                    if dead:
+                        # Graceful degradation: park every active cell with
+                        # a lane on a dead host, finish its row from the
+                        # bracket *at the dropout* (degraded=True, never
+                        # silent), and re-plan the mesh.  The rest of the
+                        # atlas keeps bisecting.
+                        lane_dead = rz.host_lane_mask(Bp, ndev, dead)
+                        per = Bp // ndev
+                        for ci in sorted(active):
+                            sl = lane_of[ci]
+                            if lane_dead[sl].any():
+                                active.discard(ci)
+                                park[sl] = True
+                                rows[ci] = _finish_row(
+                                    cells[ci], bounds[ci], steps[ci],
+                                    machines[ci], probes_of[ci],
+                                    degraded=True, bucket=bkt,
+                                    n_requeues=attempt[ci])
+                                hosts = sorted({
+                                    l // per
+                                    for l in range(sl.start, sl.stop)
+                                    if lane_dead[l]})
+                                degraded[ci] = "host_dropout:" + ",".join(
+                                    f"host{h}" for h in hosts)
+                                changed = True
+                        if (recovery is None
+                                or set(dead) != set(recovery.evict)):
+                            from repro.runtime.fault import plan_recovery
+                            recovery = plan_recovery(
+                                ndev, 1, [f"host{h}" for h in dead], [], 1)
+                for ci in sorted(active):
+                    sl = lane_of[ci]
+                    v = verdicts[sl]
+                    # Adaptive horizon: attempt a probes up to
+                    # n_chunks << a launches — verdict latching lives in the
+                    # window config, not T, so a doubled horizon is just
+                    # more chunk launches of the same program.
+                    horizon = n_chunks << attempt[ci]
+                    finished = chunks_used[ci] >= horizon or (
+                        early_stop and bool(np.all(v != VERDICT_UNDECIDED)))
+                    if not finished:
+                        continue
+                    # --- harvest: the exact RateProbe the sequential path
+                    # would have built from run_fleet's finalize metrics.
+                    k = pending[ci]
+                    cell_T = runner.T << attempt[ci]
+                    names = tuple(VERDICT_NAMES[int(x)] for x in v)
+                    sustainable = all(n == "STABLE" for n in names)
+                    d_eff = np.where(v != VERDICT_UNDECIDED,
+                                     decided_at[sl], cell_T)
+                    saved = (int(np.sum(cell_T - d_eff)) if vcfg.freeze
+                             else 0)
+                    probes_of[ci].append(RateProbe(
+                        rate_index=k, call_index=attempt[ci],
+                        lam=k * steps[ci],
+                        sustainable=sustainable, verdicts=names,
+                        decided_at=tuple(int(x) for x in d_eff),
+                        slots_run=S * cell_T - saved, slots_saved=saved,
+                        undecided=(not sustainable
+                                   and "UNSTABLE" not in names)))
+                    harvested += 1
+                    seq_launches += chunks_used[ci]
+                    launch_slots_saved += \
+                        S * (horizon - chunks_used[ci]) * runner.chunk
+                    machines[ci].record(k, sustainable,
+                                        probes_of[ci][-1].undecided)
                     k2 = machines[ci].next_rate_index()
-                if k2 is None:
-                    active.discard(ci)
-                    park[sl] = True
-                    rows[ci] = _finish_row(cells[ci], bounds[ci],
-                                           steps[ci], machines[ci],
-                                           probes_of[ci], bucket=bkt,
-                                           n_requeues=attempt[ci])
-                else:
-                    reset[sl] = True
-                    _assign(ci, k2)
-                changed = True
+                    if k2 is None and (machines[ci].undecided_hi
+                                       or machines[ci].k_lo == 0) \
+                            and attempt[ci] < max_requeues:
+                        # Re-queue (DESIGN.md §13): either the bracket top
+                        # is blocked by UNDECIDED-at-horizon evidence only,
+                        # or the bracket fully collapsed (k_lo == 0: no rate
+                        # proved sustainable).  The collapse case covers the
+                        # low-rate false-UNSTABLE artifact — at rates far
+                        # below capacity the backpressure gradient fills so
+                        # slowly that the whole horizon sits inside the
+                        # transient and the drift + gap tests both latch
+                        # UNSTABLE (paper_grid topo_seeds 5/8/15 at T=4096
+                        # read proven-UNSTABLE at 0.1x their own exact
+                        # bound); genuinely-capacity-0 cells (wireless_grid)
+                        # burn the re-queue ladder and still report 0, which
+                        # the bench asserts.  Restart the whole search from
+                        # the original integer bracket with a doubled chunk
+                        # budget instead of reporting the collapsed bracket.
+                        # The fresh machine replays the deterministic probe
+                        # order; _assign stamps the bumped call_index into
+                        # every fold_seed.
+                        attempt[ci] += 1
+                        n_requeues += 1
+                        machines[ci] = Bisection(k_lo=k_lo0[ci],
+                                                 k_hi=k_hi0[ci],
+                                                 max_calls=max_calls)
+                        k2 = machines[ci].next_rate_index()
+                    if k2 is None:
+                        active.discard(ci)
+                        park[sl] = True
+                        rows[ci] = _finish_row(cells[ci], bounds[ci],
+                                               steps[ci], machines[ci],
+                                               probes_of[ci], bucket=bkt,
+                                               n_requeues=attempt[ci])
+                    else:
+                        reset[sl] = True
+                        _assign(ci, k2)
+                    changed = True
+                sp.n = harvested
             if changed and active:
                 # Replicas mirror the last real lane's fate so they stay
                 # bit-synchronized with (or parked alongside) their source.
                 # No rewrite once the group drains: nothing launches again.
-                reset[B:] = reset[B - 1]
-                park[B:] = park[B - 1]
-                lam_host[B:] = lam_host[B - 1]
-                seed_host[B:] = seed_host[B - 1]
-                carry = rewrite_fn(pp, jnp.asarray(reset),
-                                   jnp.asarray(park), carry)
+                with span("atlas.rewrite", n=int((reset | park)[:B].sum())):
+                    reset[B:] = reset[B - 1]
+                    park[B:] = park[B - 1]
+                    lam_host[B:] = lam_host[B - 1]
+                    seed_host[B:] = seed_host[B - 1]
+                    carry = rewrite_fn(pp, jnp.asarray(reset),
+                                       jnp.asarray(park), carry)
                 n_rewrites += 1
             if sink is not None:
-                sink.write(_atlas_record(
-                    g, bkt, n_requeues, g_launches, runner.chunk, B, cells,
-                    cidx, active, machines, steps, bounds, probes_of,
-                    verdicts[:B]))
+                with span("atlas.record"):
+                    sink.write(_atlas_record(
+                        g, bkt, n_requeues, g_launches, runner.chunk, B,
+                        cells, cidx, active, machines, steps, bounds,
+                        probes_of, verdicts[:B]))
 
             if rt is not None and rt.should_snapshot(n_launches):
-                rt.snapshot(n_launches, carry, _atlas_extra(
-                    g, g_launches, n_launches, seq_launches, n_rewrites,
-                    launch_slots_saved, n_step_compiles, machines, rows,
-                    pending, chunks_used, probes_of, cidx, lam_host,
-                    seed_host, active, degraded, recovery, attempt,
-                    n_requeues, bucket_launches))
+                with span("atlas.snapshot"):
+                    rt.snapshot(n_launches, carry, _atlas_extra(
+                        g, g_launches, n_launches, seq_launches, n_rewrites,
+                        launch_slots_saved, n_step_compiles, machines, rows,
+                        pending, chunks_used, probes_of, cidx, lam_host,
+                        seed_host, active, degraded, recovery, attempt,
+                        n_requeues, bucket_launches))
             if rt is not None:
                 try:
                     rt.maybe_preempt(n_launches)
@@ -628,22 +662,23 @@ def sweep_lambda_max(cells: Sequence[AtlasJob], *,
                         sink.close()
                     raise
 
-        if group_last:
-            # One readout per policy group, after its *last* bucket: the
-            # jit cache holds one trace per bucket shape, so summing per
-            # bucket would double-count earlier buckets of the same group.
-            n_step_compiles += int(step_fn._cache_size())
+        if first_cache is not None:
+            # The unit's program, plus every trace its launches added after
+            # the first: a retrace inside the loop counts, a program that an
+            # earlier sweep in this process compiled counts once, as here.
+            n_step_compiles += 1 + int(step_fn._cache_size()) - first_cache
 
         if rt is not None and rt.should_snapshot(n_launches):
             # Unit-end marker: empty carry, cursor at the next unit's
             # start — a resume here re-enters the fresh path with the
             # restored machines re-pulling the same deterministic grid.
-            rt.snapshot(n_launches, (), _atlas_extra(
-                g + 1, 0, n_launches, seq_launches, n_rewrites,
-                launch_slots_saved, n_step_compiles, machines, rows,
-                {}, {}, {ci: [] for ci in cidx}, cidx, lam_host,
-                seed_host, set(), degraded, recovery, attempt,
-                n_requeues, bucket_launches))
+            with span("atlas.snapshot"):
+                rt.snapshot(n_launches, (), _atlas_extra(
+                    g + 1, 0, n_launches, seq_launches, n_rewrites,
+                    launch_slots_saved, n_step_compiles, machines, rows,
+                    {}, {}, {ci: [] for ci in cidx}, cidx, lam_host,
+                    seed_host, set(), degraded, recovery, attempt,
+                    n_requeues, bucket_launches))
 
     if sink is not None:
         sink.close()

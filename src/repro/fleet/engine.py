@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.obs.spans import span, spanned
 from repro.runtime.fault import RecoveryPlan, plan_recovery
 
 from repro.core.graph import ComputeProblem
@@ -210,33 +211,41 @@ def _make_stream_runner(cfg: PolicyConfig, T: int, chunk: int,
         state, stats, drift, mod, t = carry
         kt = jax.random.fold_in(key, t)
         k_arr, k_ev, k_step = jax.random.split(kt, 3)
-        if slot_arr is None:
-            arr, mod2 = jax.lax.switch(akind, arrival_branches, k_arr, lam,
-                                       mod)
-        else:
-            arr, mod2 = slot_arr, mod
-        esc, csc, mod2 = jax.lax.switch(ekind, event_branches, pp, t, k_ev,
-                                        mod2)
+        # Named scopes (`repro.obs.spans.SLOT_SCOPES`) label the slot's
+        # parts in a device trace; `slot_step` names its own sub-steps.
+        with jax.named_scope("arrive"):
+            if slot_arr is None:
+                arr, mod2 = jax.lax.switch(akind, arrival_branches, k_arr,
+                                           lam, mod)
+            else:
+                arr, mod2 = slot_arr, mod
+        with jax.named_scope("events"):
+            esc, csc, mod2 = jax.lax.switch(ekind, event_branches, pp, t,
+                                            k_ev, mod2)
         new_state, m = slot_step(pp.with_capacity_scales(esc, csc), cfg,
                                  state, arr, k_step, eps_b=eps_b)
         tq = m["total_queue"]
-        sq, cq = kahan_add(stats.sum_queue, stats.c_queue, tq)
-        s3, c3 = kahan_add(stats.sum_queue_q3, stats.c_q3,
-                           tq * ((t >= q3_lo) & (t < q4_lo)))
-        s4, c4 = kahan_add(stats.sum_queue_q4, stats.c_q4, tq * (t >= q4_lo))
-        new_stats = StreamStats(
-            sum_queue=sq, c_queue=cq,
-            sum_queue_q3=s3, c_q3=c3,
-            sum_queue_q4=s4, c_q4=c4,
-            max_queue=jnp.maximum(stats.max_queue, tq),
-            useful_at_mark=jnp.where(t == mark - 1, m["delivered_useful"],
-                                     stats.useful_at_mark),
-        )
-        new_drift = drift_verdict_update(
-            drift, t, tq, m["delivered_useful"], lam,
-            window=vwin, burn_in=vburn, k_stable=vcfg.k_stable,
-            k_unstable=vcfg.k_unstable, drift_tol=vcfg.drift_tol,
-            gap_tol=vcfg.gap_tol)
+        with jax.named_scope("stats"):
+            sq, cq = kahan_add(stats.sum_queue, stats.c_queue, tq)
+            s3, c3 = kahan_add(stats.sum_queue_q3, stats.c_q3,
+                               tq * ((t >= q3_lo) & (t < q4_lo)))
+            s4, c4 = kahan_add(stats.sum_queue_q4, stats.c_q4,
+                               tq * (t >= q4_lo))
+            new_stats = StreamStats(
+                sum_queue=sq, c_queue=cq,
+                sum_queue_q3=s3, c_q3=c3,
+                sum_queue_q4=s4, c_q4=c4,
+                max_queue=jnp.maximum(stats.max_queue, tq),
+                useful_at_mark=jnp.where(t == mark - 1,
+                                         m["delivered_useful"],
+                                         stats.useful_at_mark),
+            )
+        with jax.named_scope("verdict"):
+            new_drift = drift_verdict_update(
+                drift, t, tq, m["delivered_useful"], lam,
+                window=vwin, burn_in=vburn, k_stable=vcfg.k_stable,
+                k_unstable=vcfg.k_unstable, drift_tol=vcfg.drift_tol,
+                gap_tol=vcfg.gap_tol)
         new_carry = (new_state, new_stats, new_drift, mod2, t + 1)
         if vcfg.freeze:
             # Per-sim freeze mask: a sim whose verdict latched *before*
@@ -245,9 +254,10 @@ def _make_stream_runner(cfg: PolicyConfig, T: int, chunk: int,
             # decided_at) while the rest of the padded batch keeps
             # running.  where(False, old, new) is exactly `new`, so
             # undecided sims are bit-identical to a freeze-free run.
-            frozen = drift.verdict != VERDICT_UNDECIDED
-            new_carry = jax.tree_util.tree_map(
-                lambda o, n: jnp.where(frozen, o, n), carry, new_carry)
+            with jax.named_scope("freeze"):
+                frozen = drift.verdict != VERDICT_UNDECIDED
+                new_carry = jax.tree_util.tree_map(
+                    lambda o, n: jnp.where(frozen, o, n), carry, new_carry)
         return new_carry, None
 
     def init_carry(pp: PaddedProblem):
@@ -552,6 +562,7 @@ def _policy_group_key(job: FleetJob):
             cfg.backend)
 
 
+@spanned("fleet.job")
 def run_fleet(jobs: Sequence[FleetJob], T: int, chunk: int = 1024,
               window: int | None = None, devices=None,
               dims: PadDims | None = None,
@@ -596,6 +607,11 @@ def run_fleet(jobs: Sequence[FleetJob], T: int, chunk: int = 1024,
     checkpoint, injected launch failures retry with bounded backoff, and
     host dropouts park the dead lanes via `make_sim_rewriter` — surfaced
     in ``FleetResult.degraded``/``recovery_plan`` rather than aborting.
+
+    With `repro.obs.spans` recording, the call is one ``fleet.job`` span
+    holding ``fleet.build``, and per group ``fleet.group_setup``, one
+    ``fleet.launch`` per chunk launch, ``fleet.readout`` per early-stop
+    verdict readback and ``fleet.finalize``.
     """
     jobs = list(jobs)
     stream = stream or stream_log is not None or stream_path is not None
@@ -605,13 +621,15 @@ def run_fleet(jobs: Sequence[FleetJob], T: int, chunk: int = 1024,
     mesh = Mesh(np.array(devices), ("fleet",))
 
     # Build and pad every distinct topology once; jobs share by reference.
-    problem_of: Dict[tuple, ComputeProblem] = {}
-    for job in jobs:
-        k = (job.scenario, job.topo_seed)
-        if k not in problem_of:
-            problem_of[k] = get_scenario(job.scenario).build(job.topo_seed)
-    dims = dims or PadDims.of(list(problem_of.values()))
-    padded_of = {k: pad_problem(p, dims) for k, p in problem_of.items()}
+    with span("fleet.build"):
+        problem_of: Dict[tuple, ComputeProblem] = {}
+        for job in jobs:
+            k = (job.scenario, job.topo_seed)
+            if k not in problem_of:
+                problem_of[k] = get_scenario(job.scenario).build(
+                    job.topo_seed)
+        dims = dims or PadDims.of(list(problem_of.values()))
+        padded_of = {k: pad_problem(p, dims) for k, p in problem_of.items()}
 
     groups: Dict[tuple, List[int]] = {}
     for i, job in enumerate(jobs):
@@ -663,27 +681,36 @@ def run_fleet(jobs: Sequence[FleetJob], T: int, chunk: int = 1024,
             # device constants per group, built *before* the chunk loop.  Pad
             # the group batch to a multiple of the mesh size by repeating the
             # last job; replicas are dropped when results are scattered back.
-            B = len(idxs)
-            Bp = -(-B // ndev) * ndev
-            padded_idxs = idxs + [idxs[-1]] * (Bp - B)
-            pp = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs),
-                *[padded_of[(jobs[i].scenario, jobs[i].topo_seed)]
-                  for i in padded_idxs])
-            lam = jnp.array([jobs[i].lam for i in padded_idxs], jnp.float32)
-            eps = jnp.array([jobs[i].eps_b for i in padded_idxs], jnp.float32)
-            ak = jnp.array([arrival_code(
-                get_scenario(jobs[i].scenario).arrival)
-                for i in padded_idxs], jnp.int32)
-            ek = jnp.array([event_code(get_scenario(jobs[i].scenario).events)
-                            for i in padded_idxs], jnp.int32)
-            # One vmapped derivation instead of B host-side PRNGKey calls.
-            # int32 keeps negative seeds legal (uint32 would overflow at the
-            # host conversion); PRNGKey folds them identically either way.
-            keys = jax.vmap(jax.random.PRNGKey)(
-                jnp.array([jobs[i].seed for i in padded_idxs], jnp.int32))
+            with span("fleet.group_setup"):
+                B = len(idxs)
+                Bp = -(-B // ndev) * ndev
+                padded_idxs = idxs + [idxs[-1]] * (Bp - B)
+                pp = jax.tree_util.tree_map(
+                    lambda *xs: jnp.stack(xs),
+                    *[padded_of[(jobs[i].scenario, jobs[i].topo_seed)]
+                      for i in padded_idxs])
+                lam = jnp.array([jobs[i].lam for i in padded_idxs],
+                                jnp.float32)
+                eps = jnp.array([jobs[i].eps_b for i in padded_idxs],
+                                jnp.float32)
+                ak = jnp.array([arrival_code(
+                    get_scenario(jobs[i].scenario).arrival)
+                    for i in padded_idxs], jnp.int32)
+                ek = jnp.array([event_code(
+                    get_scenario(jobs[i].scenario).events)
+                    for i in padded_idxs], jnp.int32)
+                # One vmapped derivation instead of B host-side PRNGKey
+                # calls.  int32 keeps negative seeds legal (uint32 would
+                # overflow at the host conversion); PRNGKey folds them
+                # identically either way.
+                keys = jax.vmap(jax.random.PRNGKey)(
+                    jnp.array([jobs[i].seed for i in padded_idxs],
+                              jnp.int32))
 
-            init_fn, step_fn, fin_fn = make_group_launch(runner, mesh)
+                init_fn, step_fn, fin_fn = make_group_launch(runner, mesh)
+                resume_here = resumed is not None and g == resumed["group"]
+                if not resume_here:
+                    carry = init_fn(pp)
             emitter = None
             try:
                 if sink is not None:
@@ -692,7 +719,7 @@ def run_fleet(jobs: Sequence[FleetJob], T: int, chunk: int = 1024,
                                            runner=runner, mesh=mesh,
                                            sink=sink)
                 launched = 0
-                if resumed is not None and g == resumed["group"]:
+                if resume_here:
                     launched = resumed["launched"]
                     if launched > 0:
                         # Bit-exact restore of the donated carry at the
@@ -716,14 +743,14 @@ def run_fleet(jobs: Sequence[FleetJob], T: int, chunk: int = 1024,
                             engine="fleet",
                             ckpt_step=resumed["ckpt_step"],
                             n_preloaded=sink.n_preloaded))
-                else:
-                    carry = init_fn(pp)
                 while launched < runner.n_chunks:
-                    if rt is not None:
-                        carry = rt.launch(g, glaunch, step_fn, pp, lam, eps,
-                                          ak, ek, keys, carry)
-                    else:
-                        carry = step_fn(pp, lam, eps, ak, ek, keys, carry)
+                    with span("fleet.launch"):
+                        if rt is not None:
+                            carry = rt.launch(g, glaunch, step_fn, pp, lam,
+                                              eps, ak, ek, keys, carry)
+                        else:
+                            carry = step_fn(pp, lam, eps, ak, ek, keys,
+                                            carry)
                     launched += 1
                     glaunch += 1
                     if emitter is not None:
@@ -772,8 +799,9 @@ def run_fleet(jobs: Sequence[FleetJob], T: int, chunk: int = 1024,
                         # replicas mirror a real job) decided => the
                         # remaining chunks would only shuffle frozen bits;
                         # stop dispatching them.
-                        v = np.asarray(
-                            jax.device_get(runner.verdict_of(carry)))
+                        with span("fleet.readout"):
+                            v = np.asarray(
+                                jax.device_get(runner.verdict_of(carry)))
                         if np.all(v != VERDICT_UNDECIDED):
                             break
                 launch_saved += (len(idxs) * (runner.n_chunks - launched)
@@ -783,9 +811,10 @@ def run_fleet(jobs: Sequence[FleetJob], T: int, chunk: int = 1024,
                                          (pp, lam, eps, ak, ek, keys, carry))
                     if m is not None:
                         mem, mem_B = m, Bp
-                out = jax.device_get(fin_fn(lam, eps, carry))
-                for j, i in enumerate(idxs):
-                    metrics[i] = {k: float(v[j]) for k, v in out.items()}
+                with span("fleet.finalize"):
+                    out = jax.device_get(fin_fn(lam, eps, carry))
+                    for j, i in enumerate(idxs):
+                        metrics[i] = {k: float(v[j]) for k, v in out.items()}
             finally:
                 if emitter is not None:
                     emitter.close()   # flush in-flight records, also when

@@ -12,7 +12,10 @@ chip's choice (compiled, not interpreted):
   * the whole fleet chunk step for backend="xla" and backend="pallas" on
     one chip (the pallas step must carry the kernels as
     `tpu_custom_call`s);
-  * the xla chunk step on a four-chip lane mesh.
+  * the xla chunk step on a four-chip lane mesh;
+  * the chunk step of each benchmark cell's program (wired with the
+    freeze mask, wireless without), which must keep every named scope of
+    the slot (`repro.obs.spans.SLOT_SCOPES`) in its op metadata.
 
 Nothing runs, so nothing here is a result or a time.  The topology is
 described inside a module fixture (never at import: only one process
@@ -21,6 +24,7 @@ around these compiles, since entries written for a described chip cannot
 be read back without one.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -30,10 +34,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import PolicyConfig
 from repro.fleet import PadDims, get_scenario, pad_problem
-from repro.fleet.engine import (DEFAULT_VERDICT, _make_stream_runner,
-                                make_group_launch)
+from repro.fleet.engine import (DEFAULT_VERDICT, VerdictConfig,
+                                _make_stream_runner, make_group_launch)
 from repro.kernels import platform
 from repro.kernels.bp_slot.kernel import comp_balance_decide, slot_route_decide
+from repro.obs.spans import SLOT_SCOPES
 
 #: (N, E, NC): the atlas bucket hull, and a fat-tree-scale shape.
 KERNEL_SHAPES = [(16, 51, 4), (208, 1536, 16)]
@@ -106,7 +111,8 @@ def test_bp_slot_kernels_compile(one_chip, chip_kernels, N, E, NC, lanes):
         assert "tpu_custom_call" in compiled.as_text()
 
 
-def _compile_chunk_step(mesh, backend):
+def _compile_chunk_step(mesh, backend, wireless=False,
+                        verdict=DEFAULT_VERDICT):
     """Lower and compile the fleet's donated chunk step for `mesh`: the
     atlas's pi3 group at the pad dims of paper_grid/expander/fat_tree,
     T=4096, chunk=512.  Fresh (un-memoized) runner and launch objects,
@@ -117,9 +123,8 @@ def _compile_chunk_step(mesh, backend):
     pads = [pad_problem(problems[i % len(problems)], dims)
             for i in range(LANES)]
     pp = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *pads)
-    cfg = PolicyConfig(name="pi3", backend=backend)
-    runner = _make_stream_runner.__wrapped__(cfg, 4096, 512, None,
-                                             DEFAULT_VERDICT)
+    cfg = PolicyConfig(name="pi3", backend=backend, wireless=wireless)
+    runner = _make_stream_runner.__wrapped__(cfg, 4096, 512, None, verdict)
     _, step_fn, _ = make_group_launch.__wrapped__(runner, mesh)
     lane = jax.ShapeDtypeStruct((LANES,), jnp.float32)
     code = jax.ShapeDtypeStruct((LANES,), jnp.int32)
@@ -145,3 +150,16 @@ def test_fleet_chunk_step_compiles_four_chip_mesh(four_chips):
     compiled = _compile_chunk_step(four_chips, "xla")
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes > 0
+
+
+@pytest.mark.parametrize("wireless,freeze", [(False, True), (True, False)],
+                         ids=["wired", "wireless"])
+def test_chunk_step_keeps_slot_scopes(one_chip, wireless, freeze):
+    """The chip's compiler keeps the slot's named scopes in the op_name
+    metadata a device trace names its ops by."""
+    hlo = _compile_chunk_step(one_chip, "xla", wireless=wireless,
+                              verdict=VerdictConfig(freeze=freeze)).as_text()
+    paths = [p.split("/") for p in re.findall(r'op_name="([^"]*)"', hlo)]
+    want = {s for s in SLOT_SCOPES
+            if (wireless or s != "matching") and (freeze or s != "freeze")}
+    assert {s for s in SLOT_SCOPES if any(s in p for p in paths)} == want
