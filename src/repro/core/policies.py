@@ -102,25 +102,61 @@ class PolicyConfig:
 def greedy_maximal_matching(edges: jnp.ndarray, weights: jnp.ndarray,
                             n_nodes: int) -> jnp.ndarray:
     """Greedy maximal matching under the node-exclusive interference model
-    (paper refs [17, 18]): visit links in decreasing weight order, activate
-    a link iff neither endpoint is already busy.  Returns a [E] bool mask.
+    (paper refs [17, 18]): visit links in decreasing weight order (ties by
+    lower index), activate a link iff its weight is positive and neither
+    endpoint is already busy.  Returns a [E] bool mask.
+
+    Computed without a sort or a serial visit, as the unique fixpoint of:
+    link e is on iff w_e > 0 and no link f that shares an endpoint with e
+    and precedes it (w_f > w_e, or w_f == w_e and f < e) is on.  A link is
+    on in the greedy visit exactly when that holds, since every link that
+    precedes it has been decided by then.  Iterating the rule from "every
+    positive link on" makes each link exact once every link that precedes
+    it and shares an endpoint is, so a chain of k such links is exact
+    after k rounds and the fixpoint comes within E rounds (E - 1 for a
+    path whose weights fall along it).  `n_nodes` is not needed: conflicts
+    are read off the endpoints themselves.
+    """
+    del n_nodes
+    return _matching_fixpoint(edges, weights)[0]
+
+
+def _matching_fixpoint(edges: jnp.ndarray, weights: jnp.ndarray
+                       ) -> Tuple[jax.Array, jax.Array]:
+    """`greedy_maximal_matching` and its rounds to the fixpoint (1..E).
+
+    Dense over [E, E] link pairs: with static edges the conflict table
+    folds to a constant; with traced per-lane edges (the fleet's
+    `PaddedProblem`) it is E² compares a lane.  Padded (0, 0) self-loops
+    conflict with the links at node 0, but weigh 0 and so are never on.
     """
     E = edges.shape[0]
-    order = jnp.argsort(-weights)
+    m, l = edges[:, 0], edges[:, 1]
+    idx = jnp.arange(E)
+    conflict = ((m[:, None] == m[None, :]) | (m[:, None] == l[None, :])
+                | (l[:, None] == m[None, :]) | (l[:, None] == l[None, :]))
+    conflict &= idx[:, None] != idx[None, :]
+    w_e, w_f = weights[:, None], weights[None, :]
+    precedes = (w_f > w_e) | ((w_f == w_e) & (idx[None, :] < idx[:, None]))
+    blocks = conflict & precedes          # [e, f]: f, if on, keeps e off
+    pos = weights > 0
 
-    def body(t, carry):
-        used, sel = carry
-        e = order[t]
-        m, l = edges[e, 0], edges[e, 1]
-        ok = (~used[m]) & (~used[l]) & (weights[e] > 0)
-        used = used.at[m].set(used[m] | ok).at[l].set(used[l] | ok)
-        sel = sel.at[e].set(ok)
-        return used, sel
+    def rule(x):
+        return pos & ~jnp.any(blocks & x[None, :], axis=-1)
 
-    used0 = jnp.zeros((n_nodes,), bool)
-    sel0 = jnp.zeros((E,), bool)
-    _, sel = jax.lax.fori_loop(0, E, body, (used0, sel0))
-    return sel
+    def unsettled(carry):
+        _, changed, rounds = carry
+        return changed & (rounds <= E)
+
+    def again(carry):
+        x, _, rounds = carry
+        nx = rule(x)
+        return nx, jnp.any(nx != x), rounds + 1
+
+    x, _, rounds = jax.lax.while_loop(
+        unsettled, again, (rule(pos), jnp.asarray(True), jnp.asarray(1)))
+    # The last round only confirmed that nothing changed.
+    return x, rounds - 1
 
 
 def bp_route_slot(sp: StaticProblem, state: NetState,
@@ -134,7 +170,9 @@ def bp_route_slot(sp: StaticProblem, state: NetState,
     "zero packets" convention in expectation).
 
     wireless=True (paper §IV-C): links interfere node-exclusively; only a
-    greedy maximal matching weighted by |differential backlog| transmits.
+    greedy maximal matching weighted by |differential backlog| transmits,
+    and the metrics add `match_rounds`, the matching's rounds to its
+    fixpoint (`greedy_maximal_matching`).
 
     backend="pallas" computes the (class, comp, direction) decision with the
     fused tiled kernel `repro.kernels.bp_slot.slot_route_decide` — the
@@ -164,10 +202,11 @@ def bp_route_slot(sp: StaticProblem, state: NetState,
         emask = jnp.asarray(sp.edge_mask, jnp.float32)
         alloc = alloc * emask
         weight = weight * emask
+    metrics = {}
     if wireless:
         with jax.named_scope("matching"):
-            active = greedy_maximal_matching(jnp.asarray(sp.edges),
-                                             weight, sp.n_nodes)
+            active, metrics["match_rounds"] = _matching_fixpoint(
+                jnp.asarray(sp.edges), weight)
         alloc = alloc * active
     src = jnp.where(dmax > 0, m_idx, l_idx)
     dst = jnp.where(dmax > 0, l_idx, m_idx)
@@ -203,7 +242,7 @@ def bp_route_slot(sp: StaticProblem, state: NetState,
 
     new = state._replace(Q=Q, Ddum=Ddum, X=X, cum_arr=cum_arr)
     new = new.credit_delivery(dlv, dlv_useful)
-    return new, {"routed": jnp.sum(actual)}
+    return new, {"routed": jnp.sum(actual), **metrics}
 
 
 # ---------------------------------------------------------------------------

@@ -140,6 +140,24 @@ class TestWireless:
             edges, jnp.array([0.0, 1.0]), 4))
         np.testing.assert_array_equal(sel, [False, True])
 
+    @pytest.mark.parametrize("wireless", [True, False],
+                             ids=["wireless", "wired"])
+    def test_route_slot_reports_match_rounds(self, wireless):
+        """`match_rounds`, the matching's rounds to its fixpoint, is in
+        1..E on a wireless slot and absent on a wired one."""
+        from repro.core import StaticProblem, init_state
+        from repro.core.policies import bp_route_slot
+        sp = StaticProblem.build(paper_grid_problem())
+        rng = np.random.default_rng(3)
+        state = init_state(sp)
+        state = state._replace(Q=jnp.asarray(
+            rng.integers(0, 20, size=state.Q.shape), state.Q.dtype))
+        _, m = bp_route_slot(sp, state, wireless=wireless)
+        if wireless:
+            assert 1 <= int(m["match_rounds"]) <= sp.edges.shape[0]
+        else:
+            assert "match_rounds" not in m
+
     def test_wireless_pi3_stable_at_low_rate(self):
         p = paper_grid_problem(C=2.0)
         res = simulate(p, PolicyConfig(name="pi3", wireless=True),
