@@ -11,15 +11,35 @@ lambda* = max sum_n lam_n.  Solved exactly with scipy/HiGHS.
 The LP also supports an output-rate multiplier `rho0` on commodity (0,n)
 (rate rho0*lam_n) which models the dummy-packet overhead (1+eps_B) of
 policies pi2/pi3 (Theorem 3/5).
+
+Every solve goes through one sparse builder (`_flow_lp`, DESIGN.md §6):
+the COO indices of the directed incidence matrix are computed once with
+numpy and repeated over the commodities, so a k=8 fat-tree (7,872
+conservation rows x 49,760 columns) builds in well under a second where
+dense rows would take gigabytes.  Each build and each solve is a span
+(``capacity.build`` with its nonzeros, ``capacity.solve``) and adds to
+always-on process counters, read by `lp_counters()`.
 """
 from __future__ import annotations
 
 import dataclasses
+import time
 
 import numpy as np
+import scipy.sparse as sp
 from scipy.optimize import linprog
 
-from .graph import ComputeProblem
+from repro.obs.spans import span
+
+from .graph import ComputeProblem, Graph
+
+_COUNTERS = {"build_s": 0.0, "solve_s": 0.0, "nnz": 0, "solves": 0}
+
+
+def lp_counters() -> dict:
+    """Cumulative LP work since process start: seconds building and
+    solving, constraint-matrix nonzeros built, and solves run."""
+    return dict(_COUNTERS)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -34,85 +54,112 @@ class CapacityResult:
         return self.lam_per_node / max(tot, 1e-12)
 
 
-def _commodity_endpoints(problem: ComputeProblem) -> list[tuple[int, int]]:
-    """(src, dst) per commodity; order: for each n: (1,n), (2,n), (0,n)."""
-    eps = []
-    for n in problem.comp_nodes:
-        eps.append((problem.s1, n))
-        eps.append((problem.s2, n))
-        eps.append((n, problem.dest))
-    return eps
+@dataclasses.dataclass
+class _LP:
+    """A flow LP in HiGHS form: min obj·x, A_ub x <= b_ub, A_eq x = b_eq,
+    0 <= x <= hi.  Flow f[dir_edge, comm] is column dir_edge*K + comm;
+    the rate variables follow the nf flow columns."""
+
+    A_eq: sp.csr_matrix
+    b_eq: np.ndarray
+    A_ub: sp.csr_matrix
+    b_ub: np.ndarray
+    hi: np.ndarray
+    obj: np.ndarray
+    nf: int
+
+
+def _flow_lp(g: Graph, src, dst, var, mult, n_rate: int) -> _LP:
+    """The flow part for commodities c = (src[c] -> dst[c]) each carrying
+    mult[c] x rate variable var[c], with `n_rate` rate variables after the
+    flows (bounds [0, inf), objective 0, to be set by the caller).
+
+    Rows: conservation at every node but the commodity's destination,
+    commodity-major (row c*(N-1) + node, the destination skipped), with
+    -mult at the source; then one shared-capacity row per undirected edge
+    over both directions and every commodity.  Outflow at a commodity's
+    destination is bounded to zero (6)."""
+    src, dst, var = (np.asarray(a, np.int64) for a in (src, dst, var))
+    mult = np.asarray(mult, np.float64)
+    N, E, K = g.n_nodes, g.n_edges, len(src)
+    de = g.directed_edges().astype(np.int64)           # [2E, 2]
+    nf = 2 * E * K
+    col = np.arange(2 * E)[:, None] * K + np.arange(K)  # [2E, K]
+    base = np.arange(K) * (N - 1)
+
+    def node_rows(nodes):                               # [.., K] rows
+        return base + nodes - (nodes > dst)
+
+    tail, head = de[:, :1], de[:, 1:]
+    keep_t, keep_h = tail != dst, head != dst
+    has_src = src != dst
+    rows = np.concatenate([node_rows(tail)[keep_t], node_rows(head)[keep_h],
+                           node_rows(src)[has_src]])
+    cols = np.concatenate([col[keep_t], col[keep_h], nf + var[has_src]])
+    vals = np.concatenate([np.ones(keep_t.sum()), -np.ones(keep_h.sum()),
+                           -mult[has_src]])
+    A_eq = sp.csr_matrix((vals, (rows, cols)),
+                         shape=(K * (N - 1), nf + n_rate))
+    ub_rows = np.repeat(np.arange(E), 2 * K)
+    ub_cols = np.concatenate([col[:E], col[E:]], axis=1).ravel()
+    A_ub = sp.csr_matrix((np.ones(ub_rows.size), (ub_rows, ub_cols)),
+                         shape=(E, nf + n_rate))
+    hi = np.full(nf + n_rate, np.inf)
+    hi[col[tail == dst]] = 0.0
+    return _LP(A_eq, np.zeros(K * (N - 1)), A_ub,
+               np.asarray(g.capacity, np.float64), hi, np.zeros(nf + n_rate),
+               nf)
+
+
+def _solve(build):
+    """Build (a ``capacity.build`` span) and solve (``capacity.solve``)
+    one LP, adding both to the process counters."""
+    t0 = time.perf_counter()
+    with span("capacity.build") as s:
+        lp = build()
+        s.n = lp.A_eq.nnz + lp.A_ub.nnz
+    t1 = time.perf_counter()
+    bounds = np.column_stack([np.zeros_like(lp.hi), lp.hi])
+    with span("capacity.solve"):
+        res = linprog(lp.obj, A_ub=lp.A_ub, b_ub=lp.b_ub, A_eq=lp.A_eq,
+                      b_eq=lp.b_eq, bounds=bounds, method="highs")
+    t2 = time.perf_counter()
+    _COUNTERS["build_s"] += t1 - t0
+    _COUNTERS["solve_s"] += t2 - t1
+    _COUNTERS["nnz"] += lp.A_eq.nnz + lp.A_ub.nnz
+    _COUNTERS["solves"] += 1
+    return lp, res
+
+
+def _commodities(problem: ComputeProblem, rho0: float, first_var: int = 0):
+    """(src, dst, rate variable, multiplier) per commodity; order: for each
+    n: (1,n), (2,n), (0,n)."""
+    out = []
+    for i, n in enumerate(problem.comp_nodes):
+        v = first_var + i
+        out += [(problem.s1, n, v, 1.0), (problem.s2, n, v, 1.0),
+                (n, problem.dest, v, rho0)]
+    return out
 
 
 def capacity_upper_bound(problem: ComputeProblem, rho0: float = 1.0) -> CapacityResult:
     g = problem.graph
     NC = problem.n_comp
-    E = g.n_edges
-    de = g.directed_edges()           # [2E, 2]
     n_comm = 3 * NC
-    nf = 2 * E * n_comm               # flow variables, layout f[dir_edge, comm]
-    nv = nf + NC                      # + lam_n variables
 
-    def fidx(dir_e: int, c: int) -> int:
-        return dir_e * n_comm + c
+    def build():
+        lp = _flow_lp(g, *zip(*_commodities(problem, rho0)), n_rate=NC)
+        lp.hi[lp.nf:] = problem.comp_caps           # 0 <= lam_n <= C_n
+        lp.obj[lp.nf:] = -1.0                       # maximize sum lam_n
+        return lp
 
-    endpoints = _commodity_endpoints(problem)
-    # rate multiplier per commodity (raw commodities 1, processed rho0)
-    rate_mult = np.array([1.0, 1.0, rho0] * NC)
-
-    # --- equality: flow conservation at every node, per commodity, except at
-    # the commodity destination (conservation there is implied / slack-free
-    # because we also force zero outflow at the destination).
-    A_eq_rows, b_eq = [], []
-    for c, (src, dst) in enumerate(endpoints):
-        n_of_c = c // 3
-        for m in range(g.n_nodes):
-            if m == dst:
-                continue
-            row = np.zeros(nv)
-            for e_id, (a, b) in enumerate(de):
-                if a == m:
-                    row[fidx(e_id, c)] += 1.0    # outgoing
-                elif b == m:
-                    row[fidx(e_id, c)] -= 1.0    # incoming
-            if m == src:
-                row[nf + n_of_c] = -rate_mult[c]
-            A_eq_rows.append(row)
-            b_eq.append(0.0)
-    A_eq = np.array(A_eq_rows)
-    b_eq = np.array(b_eq)
-
-    # --- inequality: shared undirected edge capacity over all commodities+dirs
-    A_ub_rows, b_ub = [], []
-    for e in range(E):
-        row = np.zeros(nv)
-        for c in range(n_comm):
-            row[fidx(e, c)] = 1.0
-            row[fidx(e + E, c)] = 1.0
-        A_ub_rows.append(row)
-        b_ub.append(g.capacity[e])
-    A_ub = np.array(A_ub_rows)
-    b_ub = np.array(b_ub)
-
-    # --- bounds: f >= 0; zero outflow at each commodity's destination (6);
-    # 0 <= lam_n <= C_n.
-    bounds = [(0.0, None)] * nv
-    for c, (_, dst) in enumerate(endpoints):
-        for e_id, (a, _) in enumerate(de):
-            if a == dst:
-                bounds[fidx(e_id, c)] = (0.0, 0.0)
-    for i, cap in enumerate(problem.comp_caps):
-        bounds[nf + i] = (0.0, float(cap))
-
-    cobj = np.zeros(nv)
-    cobj[nf:] = -1.0                 # maximize sum lam_n
-    res = linprog(cobj, A_ub=A_ub, b_ub=b_ub, A_eq=A_eq, b_eq=b_eq,
-                  bounds=bounds, method="highs")
+    lp, res = _solve(build)
     if not res.success:
-        return CapacityResult(0.0, np.zeros(NC), np.zeros((2 * E, n_comm)),
+        return CapacityResult(0.0, np.zeros(NC),
+                              np.zeros((2 * g.n_edges, n_comm)),
                               status=res.message)
-    lam_per_node = res.x[nf:]
-    flows = res.x[:nf].reshape(2 * E, n_comm)
+    lam_per_node = res.x[lp.nf:]
+    flows = res.x[:lp.nf].reshape(2 * g.n_edges, n_comm)
     return CapacityResult(float(lam_per_node.sum()), lam_per_node, flows, "optimal")
 
 
@@ -161,94 +208,48 @@ def multi_stream_capacity(problems: list[ComputeProblem],
         np.asarray(weights, dtype=np.float64)
     assert abs(weights.sum() - 1.0) < 1e-9 and (weights > 0).all()
 
-    E = g.n_edges
-    de = g.directed_edges()
     # commodity layout: for each stream sigma, for each of its comp nodes:
-    # (1,n), (2,n), (0,n); plus lam^sigma_n variables and one global lam.
-    comm_of = []                      # (stream, endpoints, rate_var_index)
-    lam_var_of = []                   # [(stream, node_idx)]
-    for s_i, p in enumerate(problems):
-        for n_i, n in enumerate(p.comp_nodes):
-            lam_var_of.append((s_i, n_i))
-    n_lam = len(lam_var_of)
-    lam_index = {sn: i for i, sn in enumerate(lam_var_of)}
+    # (1,n), (2,n), (0,n); rate variables lam^sigma_n in the same order,
+    # then one global lam (last).
+    comms, first = [], [0]
+    for p in problems:
+        comms += _commodities(p, rho0, first[-1])
+        first.append(first[-1] + p.n_comp)
+    n_lam = first[-1]
 
-    rate_mult = []
-    for s_i, p in enumerate(problems):
-        for n_i, n in enumerate(p.comp_nodes):
-            li = lam_index[(s_i, n_i)]
-            comm_of.append((s_i, (p.s1, n), li, 1.0))
-            comm_of.append((s_i, (p.s2, n), li, 1.0))
-            comm_of.append((s_i, (n, p.dest), li, rho0))
-    n_comm = len(comm_of)
-    nf = 2 * E * n_comm
-    nv = nf + n_lam + 1               # + global lam (last)
+    def build():
+        lp = _flow_lp(g, *zip(*comms), n_rate=n_lam + 1)
+        nv = lp.nf + n_lam + 1
+        # per-stream total: sum_n lam^sigma_n - w_sigma * lam = 0
+        tot = sp.lil_matrix((NS, nv))
+        for s_i in range(NS):
+            tot[s_i, lp.nf + first[s_i]:lp.nf + first[s_i + 1]] = 1.0
+            tot[s_i, nv - 1] = -weights[s_i]
+        # shared computation capacity: sum over streams using node n
+        node_caps = {}
+        for s_i, p in enumerate(problems):
+            for n_i, n in enumerate(p.comp_nodes):
+                node_caps.setdefault(n, (p.comp_caps[n_i], []))[1].append(
+                    first[s_i] + n_i)
+        shared = sp.lil_matrix((len(node_caps), nv))
+        for r, (_, lis) in enumerate(node_caps.values()):
+            shared[r, [lp.nf + li for li in lis]] = 1.0
+        lp.A_eq = sp.vstack([lp.A_eq, tot], format="csr")
+        lp.b_eq = np.concatenate([lp.b_eq, np.zeros(NS)])
+        lp.A_ub = sp.vstack([lp.A_ub, shared], format="csr")
+        lp.b_ub = np.concatenate([lp.b_ub,
+                                  [c for c, _ in node_caps.values()]])
+        lp.obj[-1] = -1.0
+        return lp
 
-    def fidx(dir_e, c):
-        return dir_e * n_comm + c
-
-    A_eq_rows, b_eq = [], []
-    for c, (s_i, (src, dst), li, mult) in enumerate(comm_of):
-        for m in range(g.n_nodes):
-            if m == dst:
-                continue
-            row = np.zeros(nv)
-            for e_id, (a, b) in enumerate(de):
-                if a == m:
-                    row[fidx(e_id, c)] += 1.0
-                elif b == m:
-                    row[fidx(e_id, c)] -= 1.0
-            if m == src:
-                row[nf + li] = -mult
-            A_eq_rows.append(row)
-            b_eq.append(0.0)
-    # per-stream total: sum_n lam^sigma_n = w_sigma * lam
-    for s_i, p in enumerate(problems):
-        row = np.zeros(nv)
-        for n_i in range(p.n_comp):
-            row[nf + lam_index[(s_i, n_i)]] = 1.0
-        row[-1] = -weights[s_i]
-        A_eq_rows.append(row)
-        b_eq.append(0.0)
-
-    A_ub_rows, b_ub = [], []
-    for e in range(E):                # shared edge capacity
-        row = np.zeros(nv)
-        for c in range(n_comm):
-            row[fidx(e, c)] = 1.0
-            row[fidx(e + E, c)] = 1.0
-        A_ub_rows.append(row)
-        b_ub.append(g.capacity[e])
-    # shared computation capacity: sum over streams using node n
-    node_caps = {}
-    for s_i, p in enumerate(problems):
-        for n_i, n in enumerate(p.comp_nodes):
-            node_caps.setdefault(n, (p.comp_caps[n_i], []))[1].append(
-                lam_index[(s_i, n_i)])
-    for n, (cap, lis) in node_caps.items():
-        row = np.zeros(nv)
-        for li in lis:
-            row[nf + li] = 1.0
-        A_ub_rows.append(row)
-        b_ub.append(cap)
-
-    bounds = [(0.0, None)] * nv
-    for c, (s_i, (src, dst), li, mult) in enumerate(comm_of):
-        for e_id, (a, _) in enumerate(de):
-            if a == dst:
-                bounds[fidx(e_id, c)] = (0.0, 0.0)
-
-    cobj = np.zeros(nv)
-    cobj[-1] = -1.0
-    res = linprog(cobj, A_ub=np.array(A_ub_rows), b_ub=np.array(b_ub),
-                  A_eq=np.array(A_eq_rows), b_eq=np.array(b_eq),
-                  bounds=bounds, method="highs")
+    lp, res = _solve(build)
     if not res.success:
         return MultiStreamResult(0.0, np.zeros(NS),
                                  np.zeros((NS, 1)), res.message)
     lam = float(res.x[-1])
     per_stream = weights * lam
     per_node = np.zeros((NS, max(p.n_comp for p in problems)))
-    for (s_i, n_i), li in lam_index.items():
-        per_node[s_i, n_i] = res.x[nf + li]
+    for s_i, p in enumerate(problems):
+        per_node[s_i, :p.n_comp] = res.x[lp.nf + first[s_i]:
+                                         lp.nf + first[s_i + 1]]
     return MultiStreamResult(lam, per_stream, per_node, "optimal")

@@ -392,6 +392,46 @@ def fat_tree(seed: int, pods: int = 2, hosts_per_edge: int = 2,
     return ComputeProblem(g, s1, s2, dest, tuple(aggs), (C,) * len(aggs))
 
 
+def kary_fat_tree(k: int, R: float = 5.0) -> ComputeProblem:
+    """The k-ary fat-tree switch fabric of Al-Fares et al. (SIGCOMM 2008,
+    §3), computing at its aggregation layer, with the query's three hosts.
+
+    Nodes, in order: the (k/2)² core switches; then pod by pod its k/2
+    aggregation switches and its k/2 edge switches; then the hosts s1
+    (first host of pod 0), s2 (first host of pod k/2) and the sink (first
+    host of pod k-1), each on the first edge switch of its pod.  Links, in
+    order: aggregation switch i of each pod to cores i·k/2 … i·k/2+k/2-1
+    (pod by pod, switch by switch), then every edge switch of a pod to
+    every aggregation switch of it (pod, aggregation, edge), then the three
+    host links; every link carries R.  Every aggregation switch computes
+    at C_n = R/(k²/2), so the layer's total compute equals one access
+    link.  The other hosts neither send nor receive, carry no flow in any
+    LP optimum, and are left out (a server does not forward).
+    """
+    if k < 4 or k % 2:
+        raise ValueError(f"k must be even and at least 4, got {k}")
+    h = k // 2
+    n_core = h * h
+
+    def agg(p, i):
+        return n_core + p * k + i
+
+    def edge(p, j):
+        return n_core + p * k + h + j
+
+    edges = [(i * h + c, agg(p, i))
+             for p in range(k) for i in range(h) for c in range(h)]
+    edges += [(agg(p, i), edge(p, j))
+              for p in range(k) for i in range(h) for j in range(h)]
+    s1, s2, sink = n_core + k * k + np.arange(3)
+    edges += [(edge(0, 0), s1), (edge(h, 0), s2), (edge(k - 1, 0), sink)]
+    g = Graph(int(sink) + 1, np.array(edges, np.int32),
+              np.full(len(edges), R))
+    comp = tuple(agg(p, i) for p in range(k) for i in range(h))
+    return ComputeProblem(g, int(s1), int(s2), int(sink), comp,
+                          (R / (k * k / 2),) * len(comp))
+
+
 def wireless_grid(seed: int, rows: int = 4, cols: int = 4, cap: float = 5.0,
                   C: float = 2.0) -> ComputeProblem:
     """The paper-§IV-C setting: grid graph under node-exclusive interference
@@ -461,6 +501,11 @@ register_scenario(Scenario(
 register_scenario(Scenario(
     "fat_tree", fat_tree, arrival="bernoulli_batch",
     description="Mini datacenter fat-tree; bursty arrivals, agg-layer compute."))
+register_scenario(Scenario(
+    "fattree_k8", lambda seed: kary_fat_tree(8),
+    description="Al-Fares k=8 fat-tree fabric (80 switches, 256 links) "
+                "computing at its 32 aggregation switches; three query "
+                "hosts."))
 register_scenario(Scenario(
     "wireless_grid", wireless_grid, wireless=True,
     description="Grid under node-exclusive interference (greedy matching)."))

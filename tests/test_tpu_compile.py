@@ -12,7 +12,11 @@ chip's choice (compiled, not interpreted):
   * the whole fleet chunk step for backend="xla" and backend="pallas" on
     one chip (the pallas step must carry the kernels as
     `tpu_custom_call`s);
-  * the xla chunk step on a four-chip lane mesh;
+  * the xla chunk step on a four-chip lane mesh, and the atlas cell's
+    pi3 chunk step at its unreduced 192 lanes a chip over that mesh;
+  * the chunk step of the k=8 fat-tree fabric (`fattree_k8`: 83 nodes,
+    259 links, 32 computation nodes) at 64 lanes, eight seeds a rate of
+    the sweep cell's mix (the cell runs one);
   * the chunk step of each benchmark cell's program (wired with the
     freeze mask, wireless without), which must keep every named scope of
     the slot (`repro.obs.spans.SLOT_SCOPES`) in its op metadata.
@@ -112,22 +116,23 @@ def test_bp_slot_kernels_compile(one_chip, chip_kernels, N, E, NC, lanes):
 
 
 def _compile_chunk_step(mesh, backend, wireless=False,
-                        verdict=DEFAULT_VERDICT):
-    """Lower and compile the fleet's donated chunk step for `mesh`: the
-    atlas's pi3 group at the pad dims of paper_grid/expander/fat_tree,
-    T=4096, chunk=512.  Fresh (un-memoized) runner and launch objects,
-    so no trace made on the CPU is reused."""
-    problems = [get_scenario(s).build(0)
-                for s in ("paper_grid", "expander", "fat_tree")]
+                        verdict=DEFAULT_VERDICT,
+                        scenarios=("paper_grid", "expander", "fat_tree"),
+                        lanes=LANES, T=4096, chunk=512):
+    """Lower and compile the fleet's donated chunk step for `mesh`: by
+    default the atlas's pi3 group at the pad dims of
+    paper_grid/expander/fat_tree, T=4096, chunk=512.  Fresh (un-memoized)
+    runner and launch objects, so no trace made on the CPU is reused."""
+    problems = [get_scenario(s).build(0) for s in scenarios]
     dims = PadDims.of(problems)
     pads = [pad_problem(problems[i % len(problems)], dims)
-            for i in range(LANES)]
+            for i in range(lanes)]
     pp = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *pads)
     cfg = PolicyConfig(name="pi3", backend=backend, wireless=wireless)
-    runner = _make_stream_runner.__wrapped__(cfg, 4096, 512, None, verdict)
+    runner = _make_stream_runner.__wrapped__(cfg, T, chunk, None, verdict)
     _, step_fn, _ = make_group_launch.__wrapped__(runner, mesh)
-    lane = jax.ShapeDtypeStruct((LANES,), jnp.float32)
-    code = jax.ShapeDtypeStruct((LANES,), jnp.int32)
+    lane = jax.ShapeDtypeStruct((lanes,), jnp.float32)
+    code = jax.ShapeDtypeStruct((lanes,), jnp.int32)
     keys = jax.eval_shape(jax.vmap(jax.random.PRNGKey), code)
     carry = jax.eval_shape(jax.vmap(runner.init_carry), pp)
     shard = NamedSharding(mesh, P("fleet"))
@@ -150,6 +155,27 @@ def test_fleet_chunk_step_compiles_four_chip_mesh(four_chips):
     compiled = _compile_chunk_step(four_chips, "xla")
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes > 0
+
+
+def test_atlas_chunk_step_compiles_at_192_lanes_a_chip(four_chips):
+    """The `fig5_grid.atlas_4chip` cell's shape: the paper grid's pi3
+    chunk step at 128 replicas x 3 seeds x 2 policies = 768 lanes, 192 a
+    chip, over the 2x2 mesh."""
+    compiled = _compile_chunk_step(four_chips, "xla",
+                                   verdict=VerdictConfig(freeze=True),
+                                   scenarios=("paper_grid",), lanes=768)
+    ma = compiled.memory_analysis()
+    assert 0 < ma.argument_size_in_bytes + ma.temp_size_in_bytes < 16e9
+
+
+def test_fat_tree_chunk_step_compiles_one_chip(one_chip):
+    """The `fattree_k8.sweep` cell's program at up to eight seeds a rate:
+    the k=8 fabric's pi3 chunk step, 64 lanes of [83, 3, 32] queues,
+    chunk 20,000 of T 120,000."""
+    compiled = _compile_chunk_step(one_chip, "xla", scenarios=("fattree_k8",),
+                                   lanes=64, T=120_000, chunk=20_000)
+    ma = compiled.memory_analysis()
+    assert 0 < ma.argument_size_in_bytes + ma.temp_size_in_bytes < 16e9
 
 
 @pytest.mark.parametrize("wireless,freeze", [(False, True), (True, False)],
