@@ -159,6 +159,116 @@ def _matching_fixpoint(edges: jnp.ndarray, weights: jnp.ndarray
     return x, rounds - 1
 
 
+def _dot(spec: str, a: jax.Array, b: jax.Array) -> jax.Array:
+    """A contraction at f32 precision: the TPU's default rounds f32
+    operands to bfloat16; HIGHEST splits each into bfloat16 parts that sum
+    back to it, so a one-hot contraction with one term is exact."""
+    return jnp.einsum(spec, a, b, precision=jax.lax.Precision.HIGHEST)
+
+
+class _Slots:
+    """The links that meet a row (a node), each in its place there
+    (`queues.link_places`, carried by the problem as `edge_places`): slot
+    (row, r) holds the link of place r, if any.
+
+    Links reach their slots, and per-row tables reach their links, by
+    contractions of one-hot incidences in which each output has at most
+    one term: exact in any order.  Adding a row's slots one place after
+    another adds its links in link order, and so rounds as a scatter-add
+    in link order does."""
+
+    def __init__(self, row: jax.Array, place: jax.Array, rows: int,
+                 places: int, take: jax.Array):
+        self.inc = ((row[:, None] == jnp.arange(rows))
+                    & take[:, None]).astype(jnp.float32)       # [E, rows]
+        self.place = (place[:, None] == jnp.arange(places)
+                      ).astype(jnp.float32)                    # [E, places]
+
+    def per_slot(self, *per_link: jax.Array) -> Tuple[jax.Array, ...]:
+        """[E] each -> [rows, places] each: each slot's link's value (0
+        where a slot is empty)."""
+        v = jnp.stack(per_link, axis=-1).astype(jnp.float32)    # [E, k]
+        at = _dot("en,erk->nrk", self.inc,
+                  self.place[:, :, None] * v[:, None, :])
+        return tuple(at[:, :, j] for j in range(len(per_link)))
+
+    def pick(self, table: jax.Array, *cols: jax.Array
+             ) -> Tuple[jax.Array, ...]:
+        """For each `col` [E]: each link's entry table[its row, col] (0
+        for a link not taken)."""
+        rows = _dot("en,nc->ec", self.inc, table)
+        at = jnp.arange(table.shape[1])
+        return tuple(jnp.sum(jnp.where(col[:, None] == at, rows, 0.0), axis=1)
+                     for col in cols)
+
+
+def _add_in_place_order(base: jax.Array, col: jax.Array, val: jax.Array
+                        ) -> jax.Array:
+    """`base` [rows, C] plus `val` [rows, places] at the columns `col`
+    [rows, places], one place after another."""
+    cols = jnp.arange(base.shape[1])
+    for r in range(col.shape[1]):
+        base = base + jnp.where(col[:, r:r + 1] == cols, val[:, r:r + 1],
+                                0.0)
+    return base
+
+
+def _apply(sp, state: NetState, src, dst, place_src, place_dst, best,
+           alloc, real):
+    """The route's apply half: move each link's fluid from its source to
+    its destination, in link order at every queue (DESIGN.md §7)."""
+    Q, Ddum = state.Q, state.Ddum
+    NC = sp.n_comp
+    N = Q.shape[0]
+    R = sp.max_degree
+    Qf = Q.reshape(N, 3 * NC)
+    best_i, best_n = best // NC, best % NC
+    out = _Slots(src, place_src, N, R, real)           # leaving each node
+    into = _Slots(dst, place_dst, N, R, real)          # reaching each node
+
+    # Cap total outflow of each (node, class) at its queue content.
+    out_best, out_alloc = out.per_slot(best, alloc)
+    out_best = out_best.astype(best.dtype)
+    total_out = _add_in_place_order(jnp.zeros_like(Qf), out_best, out_alloc)
+    scale = jnp.where(total_out > Qf, Qf / jnp.maximum(total_out, 1e-20),
+                      1.0)
+    scale_src, q0_src, ddum_src = out.pick(
+        jnp.concatenate([scale, Qf[:, :NC], Ddum], axis=1),
+        best, 3 * NC + best_n, 4 * NC + best_n)
+    actual = alloc * scale_src                                     # [E]
+
+    # Dummy share of moved processed packets (proportional composition).
+    frac_dummy = jnp.where(q0_src > 0,
+                           ddum_src / jnp.maximum(q0_src, 1e-20), 0.0)
+    moved_dummy = actual * frac_dummy * (best_i == 0)
+
+    # Departures.
+    out_actual, out_dummy = out.per_slot(actual, moved_dummy)
+    Qf = _add_in_place_order(Qf, out_best, -out_actual)
+    Ddum = _add_in_place_order(Ddum, out_best % NC, -out_dummy)
+
+    # Arrivals: sinks absorb (raw -> X at its comp node; processed -> d).
+    sink = jnp.asarray(sp.sink).reshape(N, 3 * NC).astype(jnp.float32)
+    is_sink = into.pick(sink, best)[0] > 0.5
+    into_best, into_actual, into_dummy = into.per_slot(
+        best, actual * ~is_sink, moved_dummy * ~is_sink)
+    into_best = into_best.astype(best.dtype)
+    Qf = _add_in_place_order(Qf, into_best, into_actual)
+    Ddum = _add_in_place_order(Ddum, into_best % NC, into_dummy)
+
+    # Raw packets at their comp node: into X and cum_arr, row n, column
+    # i - 1.  They all reach comp node n, so their places there order them.
+    raw = is_sink & (best_i >= 1)
+    to_X = actual * raw
+    credit = _Slots(best_n, place_dst, NC, R, raw)
+    credit_best, credit_val = credit.per_slot(best, to_X)
+    side = credit_best.astype(best.dtype) // NC - 1
+    X = _add_in_place_order(state.X, side, credit_val)
+    cum_arr = _add_in_place_order(state.cum_arr, side, credit_val)
+    return (Qf.reshape(Q.shape), Ddum, X, cum_arr, actual, moved_dummy,
+            is_sink)
+
+
 def bp_route_slot(sp: StaticProblem, state: NetState,
                   wireless: bool = False, backend: str = "xla"
                   ) -> Tuple[NetState, Dict]:
@@ -178,8 +288,12 @@ def bp_route_slot(sp: StaticProblem, state: NetState,
     fused tiled kernel `repro.kernels.bp_slot.slot_route_decide` — the
     [E, 3*NC] differential tensor is streamed through VMEM instead of
     materialized — bit-identical to the "xla" oracle (DESIGN.md §7).
+
+    The apply half then moves the fluid (`_apply`): one-hot contractions
+    into (node, place) slots and back, no scatter and no per-link gather,
+    adding the links that share a queue in link order (DESIGN.md §7).
     """
-    Q, Ddum, X = state.Q, state.Ddum, state.X
+    Q = state.Q
     m_idx = jnp.asarray(sp.edges[:, 0])
     l_idx = jnp.asarray(sp.edges[:, 1])
     cap = jnp.asarray(sp.edge_cap)
@@ -191,7 +305,6 @@ def bp_route_slot(sp: StaticProblem, state: NetState,
     else:
         best, dmax = slot_route_ref(Qf, m_idx, l_idx)
     best_i = best // NC
-    best_n = best % NC
 
     alloc = cap * (jnp.abs(dmax) > 0)
     # A link that cannot carry traffic this slot — padded (edge_mask 0) or
@@ -208,33 +321,16 @@ def bp_route_slot(sp: StaticProblem, state: NetState,
             active, metrics["match_rounds"] = _matching_fixpoint(
                 jnp.asarray(sp.edges), weight)
         alloc = alloc * active
-    src = jnp.where(dmax > 0, m_idx, l_idx)
-    dst = jnp.where(dmax > 0, l_idx, m_idx)
-
-    # Cap total outflow of each (node, class) at its queue content.
-    total_out = jnp.zeros_like(Q).at[src, best_i, best_n].add(alloc)
-    scale = jnp.where(total_out > Q, Q / jnp.maximum(total_out, 1e-20), 1.0)
-    actual = alloc * scale[src, best_i, best_n]    # [E]
-
-    # Dummy share of moved processed packets (proportional composition).
-    q0_src = Q[src, 0, best_n]
-    frac_dummy = jnp.where(q0_src > 0, Ddum[src, best_n] / jnp.maximum(q0_src, 1e-20), 0.0)
-    moved_dummy = actual * frac_dummy * (best_i == 0)
-
-    # Departures.
-    Q = Q.at[src, best_i, best_n].add(-actual)
-    Ddum = Ddum.at[src, best_n].add(-moved_dummy)
-
-    # Arrivals: sinks absorb (raw -> X at its comp node; processed -> d).
-    is_sink = jnp.asarray(sp.sink)[dst, best_i, best_n]          # [E]
-    to_net = actual * (~is_sink)
-    Q = Q.at[dst, best_i, best_n].add(to_net)
-    Ddum = Ddum.at[dst, best_n].add(moved_dummy * (~is_sink))
-
-    raw_sink = is_sink & (best_i >= 1)
-    to_X = actual * raw_sink
-    X = X.at[best_n, jnp.maximum(best_i - 1, 0)].add(to_X)
-    cum_arr = state.cum_arr.at[best_n, jnp.maximum(best_i - 1, 0)].add(to_X)
+    fwd = dmax > 0
+    src = jnp.where(fwd, m_idx, l_idx)
+    dst = jnp.where(fwd, l_idx, m_idx)
+    places = jnp.asarray(sp.edge_places)
+    place_m, place_l = places[:, 0], places[:, 1]
+    real = (jnp.ones(fwd.shape, bool) if sp.edge_mask is None
+            else jnp.asarray(sp.edge_mask) > 0)
+    Q, Ddum, X, cum_arr, actual, moved_dummy, is_sink = _apply(
+        sp, state, src, dst, jnp.where(fwd, place_m, place_l),
+        jnp.where(fwd, place_l, place_m), best, alloc, real)
 
     proc_sink = is_sink & (best_i == 0)
     dlv = jnp.sum(actual * proc_sink)

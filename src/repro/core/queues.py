@@ -204,6 +204,18 @@ class StaticProblem:
     edge_mask: np.ndarray | None = None   # [E] float32, 1.0 = active
     comp_mask: np.ndarray | None = None   # [NC] float32, 1.0 = active
 
+    @property
+    def max_degree(self) -> int:
+        """The most links that meet at one node: the places the route's
+        apply half gives each node (`policies._apply`)."""
+        return max_node_degree(np.asarray(self.edges))
+
+    @property
+    def edge_places(self) -> np.ndarray:
+        """[E, 2]: each link's place at its m end and at its l end
+        (`link_places`)."""
+        return link_places(np.asarray(self.edges))
+
     @staticmethod
     def build(problem: ComputeProblem) -> "StaticProblem":
         N = problem.graph.n_nodes
@@ -225,6 +237,25 @@ class StaticProblem:
             comp_caps=np.asarray(problem.comp_caps, dtype=np.float32),
             sink=sink,
         )
+
+
+def link_places(edges: np.ndarray) -> np.ndarray:
+    """[E, 2] int32: each link's place, in link order, among the links
+    that meet its m end, and among those that meet its l end.  The links
+    at one node hold the places 0, 1, ... (a self-loop one place)."""
+    seen = np.zeros(int(edges.max(initial=0)) + 1, np.int32)
+    places = np.zeros(edges.shape, np.int32)
+    for e, (m, l) in enumerate(edges):
+        places[e] = seen[m], seen[l]
+        seen[m] += 1
+        if l != m:
+            seen[l] += 1
+    return places
+
+
+def max_node_degree(edges: np.ndarray) -> int:
+    """The most links of `edges` that meet at one node, at least 1."""
+    return int(link_places(edges).max(initial=0)) + 1
 
 
 def init_state(sp: StaticProblem) -> NetState:

@@ -301,7 +301,8 @@ def sweep_lambda_max(cells: Sequence[AtlasJob], *,
     dims = PadDims(
         n_nodes=max(d.n_nodes for d in bucket_dims),
         n_edges=max(d.n_edges for d in bucket_dims),
-        n_comp=max(d.n_comp for d in bucket_dims))
+        n_comp=max(d.n_comp for d in bucket_dims),
+        max_degree=max(d.places for d in bucket_dims))
     padded_of = {k: pad_problem(p, bucket_dims[bucket_of[k]])
                  for k, p in problem_of.items()}
     cell_bucket = [bucket_of[(c.scenario, c.topo_seed)] for c in cells]

@@ -21,6 +21,9 @@ referenced by README and the core policies):
     (``F = 0``), so padded slots accumulate no ``Y``/``H``/``Ddum`` state —
     padding is the network-side mirror of the paper's dummy-packet
     regulator (DESIGN.md §2).
+  * ``edge_places`` of padded edges are 0: they follow the real edges, so
+    they never take a place before a real one, and the route's apply half
+    moves nothing on them (``max_degree``, static, bounds the real places).
   * ``sink`` rows of padded classes are all ``False``; padded *nodes* simply
     host queues that never receive traffic (no active edge touches them).
 
@@ -38,7 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.graph import ComputeProblem
-from repro.core.queues import StaticProblem
+from repro.core.queues import StaticProblem, max_node_degree
 
 
 @jax.tree_util.register_pytree_node_class
@@ -58,16 +61,18 @@ class PaddedProblem:
     sink: jax.Array            # [..., N, 3, NC] bool
     edge_mask: jax.Array       # [..., E] float32
     comp_mask: jax.Array       # [..., NC] float32
+    edge_places: jax.Array     # [..., E, 2] int32 (`link_places`)
+    max_degree: int            # static: places a node gives its links
 
     def tree_flatten(self):
         leaves = (self.edges, self.edge_cap, self.s1, self.s2, self.dest,
                   self.comp_nodes, self.comp_caps, self.sink,
-                  self.edge_mask, self.comp_mask)
-        return leaves, (self.n_nodes, self.n_comp)
+                  self.edge_mask, self.comp_mask, self.edge_places)
+        return leaves, (self.n_nodes, self.n_comp, self.max_degree)
 
     @classmethod
     def tree_unflatten(cls, aux, leaves):
-        return cls(aux[0], aux[1], *leaves)
+        return cls(aux[0], aux[1], *leaves, aux[2])
 
     @property
     def n_edges(self) -> int:
@@ -100,11 +105,21 @@ def problem_shape(problem: ComputeProblem) -> Tuple[int, int, int]:
             int(problem.n_comp))
 
 
+def problem_degree(problem: ComputeProblem) -> int:
+    """The most links that meet at one node of the instance."""
+    return max_node_degree(np.asarray(problem.graph.edges))
+
+
 @dataclasses.dataclass(frozen=True)
 class PadDims:
+    """The padded shapes, and `max_degree`: the places each node gives
+    the links that meet it in the route's apply half (None: `n_edges`,
+    which bounds any instance that fits)."""
+
     n_nodes: int
     n_edges: int
     n_comp: int
+    max_degree: int | None = None
 
     @staticmethod
     def of(problems: Sequence[ComputeProblem]) -> "PadDims":
@@ -118,11 +133,17 @@ class PadDims:
             n_nodes=max(p.graph.n_nodes for p in problems),
             n_edges=max(p.graph.n_edges for p in problems),
             n_comp=max(p.n_comp for p in problems),
+            max_degree=max(problem_degree(p) for p in problems),
         )
+
+    @property
+    def places(self) -> int:
+        return self.n_edges if self.max_degree is None else self.max_degree
 
     def fits(self, problem: ComputeProblem) -> bool:
         n, e, nc = problem_shape(problem)
-        return n <= self.n_nodes and e <= self.n_edges and nc <= self.n_comp
+        return (n <= self.n_nodes and e <= self.n_edges and nc <= self.n_comp
+                and problem_degree(problem) <= self.places)
 
 
 def make_buckets(problems: Sequence[ComputeProblem],
@@ -189,21 +210,23 @@ def validate_buckets(problems: Sequence[ComputeProblem],
             n, e, nc = problem_shape(p)
             raise ValueError(
                 f"validate_buckets: problem {i} with shape (n_nodes={n}, "
-                f"n_edges={e}, n_comp={nc}) exceeds bucket {b} dims "
-                f"(n_nodes={d.n_nodes}, n_edges={d.n_edges}, "
-                f"n_comp={d.n_comp})")
+                f"n_edges={e}, n_comp={nc}, max_degree={problem_degree(p)}) "
+                f"exceeds bucket {b} dims (n_nodes={d.n_nodes}, "
+                f"n_edges={d.n_edges}, n_comp={d.n_comp}, "
+                f"max_degree={d.places})")
 
 
 def pad_problem(problem: ComputeProblem, dims: PadDims) -> PaddedProblem:
     """Embed one ComputeProblem into the fleet-wide padded shapes."""
     sp = StaticProblem.build(problem)
     N, E, NC = dims.n_nodes, dims.n_edges, dims.n_comp
-    e, nc = sp.edges.shape[0], sp.n_comp
-    if sp.n_nodes > N or e > E or nc > NC:
+    e, nc, deg = sp.edges.shape[0], sp.n_comp, sp.max_degree
+    if sp.n_nodes > N or e > E or nc > NC or deg > dims.places:
         raise ValueError(
             f"pad_problem: instance shape (n_nodes={sp.n_nodes}, "
-            f"n_edges={e}, n_comp={nc}) exceeds pad dims (n_nodes={N}, "
-            f"n_edges={E}, n_comp={NC}) — pass PadDims.of over every "
+            f"n_edges={e}, n_comp={nc}, max_degree={deg}) exceeds pad dims "
+            f"(n_nodes={N}, n_edges={E}, n_comp={NC}, "
+            f"max_degree={dims.places}) — pass PadDims.of over every "
             f"problem in the batch (or its bucket)")
 
     edges = np.zeros((E, 2), np.int32)               # padding: self-loop (0,0)
@@ -212,6 +235,8 @@ def pad_problem(problem: ComputeProblem, dims: PadDims) -> PaddedProblem:
     edge_cap[:e] = sp.edge_cap
     edge_mask = np.zeros((E,), np.float32)
     edge_mask[:e] = 1.0
+    edge_places = np.zeros((E, 2), np.int32)
+    edge_places[:e] = sp.edge_places
 
     comp_nodes = np.zeros((NC,), np.int32)           # padding: node 0, cap 0
     comp_nodes[:nc] = sp.comp_nodes
@@ -230,6 +255,7 @@ def pad_problem(problem: ComputeProblem, dims: PadDims) -> PaddedProblem:
         comp_nodes=jnp.asarray(comp_nodes), comp_caps=jnp.asarray(comp_caps),
         sink=jnp.asarray(sink),
         edge_mask=jnp.asarray(edge_mask), comp_mask=jnp.asarray(comp_mask),
+        edge_places=jnp.asarray(edge_places), max_degree=dims.places,
     )
 
 

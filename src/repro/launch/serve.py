@@ -84,7 +84,10 @@ class Engine:
                     _, self.caches = self._step(self.params, self.caches,
                                                 jnp.asarray(toks),
                                                 self.router_H)
-                    self._last_tok = np.asarray(toks)
+                    # A copy: the step may still read `toks` (jnp.asarray
+                    # aliases host memory on the CPU), and _last_tok is
+                    # written below.
+                    self._last_tok = toks.copy()
                 self._last_tok[s] = req.prompt[-1]
 
     def step(self) -> int:

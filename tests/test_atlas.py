@@ -434,7 +434,8 @@ class TestBucketedEquivalence:
         assert res.dims == PadDims(
             n_nodes=max(d.n_nodes for d in res.bucket_dims),
             n_edges=max(d.n_edges for d in res.bucket_dims),
-            n_comp=max(d.n_comp for d in res.bucket_dims))
+            n_comp=max(d.n_comp for d in res.bucket_dims),
+            max_degree=max(d.max_degree for d in res.bucket_dims))
 
     def test_rows_bit_identical_to_single_bucket_at_bucket_dims(
             self, bucketed):

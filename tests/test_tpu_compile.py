@@ -19,7 +19,10 @@ chip's choice (compiled, not interpreted):
     the sweep cell's mix (the cell runs one);
   * the chunk step of each benchmark cell's program (wired with the
     freeze mask, wireless without), which must keep every named scope of
-    the slot (`repro.obs.spans.SLOT_SCOPES`) in its op metadata.
+    the slot (`repro.obs.spans.SLOT_SCOPES`) in its op metadata;
+  * the atlas (96 lanes) and fat-tree (8 lanes) chunk steps, whose
+    `route` scope must hold no scatter, and no contraction below HIGHEST
+    precision.
 
 Nothing runs, so nothing here is a result or a time.  The topology is
 described inside a module fixture (never at import: only one process
@@ -189,3 +192,30 @@ def test_chunk_step_keeps_slot_scopes(one_chip, wireless, freeze):
     want = {s for s in SLOT_SCOPES
             if (wireless or s != "matching") and (freeze or s != "freeze")}
     assert {s for s in SLOT_SCOPES if any(s in p for p in paths)} == want
+
+
+@pytest.mark.parametrize("scenario,lanes,T,chunk,freeze", [
+    ("paper_grid", 96, 4096, 512, True),
+    ("fattree_k8", 8, 120_000, 20_000, False),
+], ids=["atlas", "fattree_k8"])
+def test_route_apply_has_no_scatter(one_chip, scenario, lanes, T, chunk,
+                                    freeze):
+    """The route's apply half reaches the chip without a scatter, at the
+    atlas's shape and at the largest a cell runs: no scatter under the
+    `route` scope, and no contraction there below HIGHEST precision (f32,
+    not bfloat16 operands).  The compiler drops the op_name of scatters
+    it rewrites, so an unnamed scatter counts as one that could be the
+    route's."""
+    hlo = _compile_chunk_step(one_chip, "xla", scenarios=(scenario,),
+                              lanes=lanes, T=T, chunk=chunk,
+                              verdict=VerdictConfig(freeze=freeze)).as_text()
+    ops = [line for line in hlo.splitlines() if " = " in line]
+    scatters = [line for line in ops if re.search(r" scatter\(", line)]
+    assert scatters, "the balance and compute scopes still scatter"
+    for line in scatters:
+        name = re.search(r'op_name="([^"]*)"', line)
+        assert name and "/route/" not in name.group(1) + "/", line[:200]
+    contractions = [line for line in ops if "/route/" in line
+                    and re.search(r" (dot|convolution)\(", line)]
+    for line in contractions:
+        assert "operand_precision={highest,highest}" in line, line[:200]
