@@ -46,18 +46,17 @@ from typing import Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
 
-from repro.core.graph import ComputeProblem
 from repro.core.queues import VERDICT_NAMES, VERDICT_UNDECIDED
 from repro.obs.spans import span, spanned
-from .batching import PadDims, make_buckets, pad_problem
+from .batching import (PadDims, lane_batch, lane_keys, mirror_replicas,
+                       pad_topologies)
+from .driver import Run
 from .engine import (FleetJob, VerdictConfig, _policy_group_key,
                      make_group_launch, make_sim_rewriter,
                      make_stream_runner, resolve_verdict)
 from .frontier import Bisection, RateProbe, fold_seed
 from .report import policy_bound_exact
-from .scenarios import arrival_code, event_code, get_scenario
 
 
 @dataclasses.dataclass(frozen=True)
@@ -251,12 +250,8 @@ def sweep_lambda_max(cells: Sequence[AtlasJob], *,
     cells = list(cells)
     if not cells:
         raise ValueError("empty atlas")
-    stream = stream or stream_log is not None or stream_path is not None
     seeds = tuple(seeds)
     vcfg = resolve_verdict(verdict, early_stop)
-    devices = list(devices or jax.devices())
-    ndev = len(devices)
-    mesh = Mesh(np.array(devices), ("fleet",))
     S = len(seeds)
 
     # --- per-cell bound, grid step, and bisection machine.  The bracket
@@ -285,26 +280,13 @@ def sweep_lambda_max(cells: Sequence[AtlasJob], *,
     # dims.  An explicit `dims` forces one shared bucket (the equivalence
     # hook); otherwise `make_buckets` cuts quantile-based size buckets
     # (DESIGN.md §13) and each problem is padded only to its bucket hull.
-    problem_of: Dict[tuple, ComputeProblem] = {}
-    for c in cells:
-        k = (c.scenario, c.topo_seed)
-        if k not in problem_of:
-            problem_of[k] = get_scenario(c.scenario).build(c.topo_seed)
-    problem_keys = list(problem_of)
-    if dims is not None:
-        bucket_dims = [dims]
-        bucket_of = {k: 0 for k in problem_keys}
-    else:
-        bucket_dims, assignment = make_buckets(
-            [problem_of[k] for k in problem_keys], n_buckets)
-        bucket_of = {k: b for k, b in zip(problem_keys, assignment)}
+    padded_of, bucket_of, bucket_dims = pad_topologies(cells, dims,
+                                                       n_buckets)
     dims = PadDims(
         n_nodes=max(d.n_nodes for d in bucket_dims),
         n_edges=max(d.n_edges for d in bucket_dims),
         n_comp=max(d.n_comp for d in bucket_dims),
         max_degree=max(d.places for d in bucket_dims))
-    padded_of = {k: pad_problem(p, bucket_dims[bucket_of[k]])
-                 for k, p in problem_of.items()}
     cell_bucket = [bucket_of[(c.scenario, c.topo_seed)] for c in cells]
 
     # --- launch units: policy groups (the only axis that forks traced
@@ -326,18 +308,6 @@ def sweep_lambda_max(cells: Sequence[AtlasJob], *,
         for b in sorted(by_bucket):
             units.append((gkey, b, by_bucket[b]))
 
-    rt = resumed = None
-    if resilience is not None:
-        from repro.runtime import resilience as rz
-        rt = rz.maybe_resilient(resilience, "atlas", cells=tuple(cells),
-                                seeds=seeds, T=T, chunk=chunk, window=window,
-                                rel_tol=rel_tol, bracket=tuple(bracket),
-                                max_calls=max_calls, early_stop=early_stop,
-                                verdict=vcfg, dims=tuple(bucket_dims),
-                                n_buckets=n_buckets,
-                                max_requeues=max_requeues, ndev=ndev)
-        resumed = rt.resumed
-
     rows: List[AtlasRow | None] = [None] * len(cells)
     attempt: List[int] = [0] * len(cells)
     n_launches = seq_launches = n_rewrites = 0
@@ -346,190 +316,161 @@ def sweep_lambda_max(cells: Sequence[AtlasJob], *,
     n_requeues = 0
     bucket_launches: Dict[int, int] = {b: 0 for b in range(len(bucket_dims))}
     eff_T = eff_chunk = 0
-    degraded: Dict[int, str] = {}
-    recovery = None
-    sink = None
-    if stream:
-        from repro.obs.emitter import StreamSink
-        sink = StreamSink(path=stream_path, log=stream_log,
-                          append=resumed is not None)
-    if resumed is not None:
-        # Host scheduler restore: every cell's machine (cells in already-
-        # finished units carry their final state; unstarted ones their
-        # initial state — both re-serialize identically), finished rows,
-        # attempt counters, and the launch counters.
-        for ci_s, ms in resumed["machines"].items():
-            machines[int(ci_s)] = Bisection.from_state(ms)
-        for ci_s, rs in resumed["rows"].items():
-            rows[int(ci_s)] = rz.row_restore(rs)
-        for ci_s, a in resumed["attempt"].items():
-            attempt[int(ci_s)] = int(a)
-        n_launches = resumed["n_launches"]
-        seq_launches = resumed["seq_launches"]
-        n_rewrites = resumed["n_rewrites"]
-        launch_slots_saved = resumed["launch_slots_saved"]
-        n_step_compiles = resumed["n_step_compiles"]
-        n_requeues = resumed["n_requeues"]
-        bucket_launches.update(
-            {int(b): int(n) for b, n in resumed["bucket_launches"].items()})
-        degraded = {int(k): v for k, v in resumed["degraded"].items()}
-        recovery = rz.plan_restore(resumed["recovery"])
+    run = Run("atlas", devices, resilience, stream, stream_log, stream_path,
+              cells=tuple(cells), seeds=seeds, T=T, chunk=chunk,
+              window=window, rel_tol=rel_tol, bracket=tuple(bracket),
+              max_calls=max_calls, early_stop=early_stop, verdict=vcfg,
+              dims=tuple(bucket_dims), n_buckets=n_buckets,
+              max_requeues=max_requeues)
+    resumed = run.resumed
+    with run:
+        if resumed is not None:
+            from repro.runtime import resilience as rz
+            # Host scheduler restore: every cell's machine (cells in already-
+            # finished units carry their final state; unstarted ones their
+            # initial state — both re-serialize identically), finished rows,
+            # attempt counters, and the launch counters.
+            for ci_s, ms in resumed["machines"].items():
+                machines[int(ci_s)] = Bisection.from_state(ms)
+            for ci_s, rs in resumed["rows"].items():
+                rows[int(ci_s)] = rz.row_restore(rs)
+            for ci_s, a in resumed["attempt"].items():
+                attempt[int(ci_s)] = int(a)
+            n_launches = resumed["n_launches"]
+            seq_launches = resumed["seq_launches"]
+            n_rewrites = resumed["n_rewrites"]
+            launch_slots_saved = resumed["launch_slots_saved"]
+            n_step_compiles = resumed["n_step_compiles"]
+            n_requeues = resumed["n_requeues"]
+            bucket_launches.update({int(b): int(n) for b, n in
+                                    resumed["bucket_launches"].items()})
 
-    for g, (gkey, bkt, cidx) in enumerate(units):
-        cfg = FleetJob(scenario=cells[cidx[0]].scenario,
-                       policy=cells[cidx[0]].policy,
-                       eps_b=cells[cidx[0]].eps_b,
-                       topo_seed=cells[cidx[0]].topo_seed).policy_config()
-        runner = make_stream_runner(cfg, T, chunk=chunk, window=window,
-                                    verdict=vcfg)
-        eff_T, eff_chunk = runner.T, runner.chunk
-        n_chunks = runner.n_chunks
-        if resumed is not None and g < resumed["group"]:
-            continue              # finished pre-kill: rows restored above
+        for g, (gkey, bkt, cidx) in enumerate(units):
+            cfg = FleetJob(scenario=cells[cidx[0]].scenario,
+                           policy=cells[cidx[0]].policy,
+                           eps_b=cells[cidx[0]].eps_b,
+                           topo_seed=cells[cidx[0]].topo_seed).policy_config()
+            runner = make_stream_runner(cfg, T, chunk=chunk, window=window,
+                                        verdict=vcfg)
+            eff_T, eff_chunk = runner.T, runner.chunk
+            n_chunks = runner.n_chunks
+            if resumed is not None and g < resumed["group"]:
+                continue              # finished pre-kill: rows restored above
 
-        with span("atlas.unit_setup") as sp:
-            # Lane layout: S contiguous lanes per cell, mesh-padded by
-            # repeating the last real lane (run_fleet's replica convention
-            # — replicas mirror every rewrite of their source cell and are
-            # never harvested).
-            lane_cells = [ci for ci in cidx for _ in seeds]
-            B = len(lane_cells)
-            Bp = -(-B // ndev) * ndev
-            lane_pad = lane_cells + [lane_cells[-1]] * (Bp - B)
-            sp.n = Bp
-            lane_of = {ci: slice(j * S, (j + 1) * S)
-                       for j, ci in enumerate(cidx)}
+            with span("atlas.unit_setup") as sp:
+                # Lane layout: S contiguous lanes per cell, mesh-padded
+                # by repeating the last real lane (`batching.lane_batch`;
+                # replicas mirror every rewrite of their source cell and
+                # are never harvested).
+                batch = lane_batch([cells[ci] for ci in cidx for _ in seeds],
+                                   padded_of, run.ndev, rates=False)
+                B, Bp, pp = batch.B, batch.Bp, batch.pp
+                sp.n = Bp
+                lane_of = {ci: slice(j * S, (j + 1) * S)
+                           for j, ci in enumerate(cidx)}
 
-            pp = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs),
-                *[padded_of[(cells[ci].scenario, cells[ci].topo_seed)]
-                  for ci in lane_pad])
-            eps = jnp.array([cells[ci].eps_b for ci in lane_pad],
-                            jnp.float32)
-            ak = jnp.array([arrival_code(
-                get_scenario(cells[ci].scenario).arrival)
-                for ci in lane_pad], jnp.int32)
-            ek = jnp.array([event_code(
-                get_scenario(cells[ci].scenario).events)
-                for ci in lane_pad], jnp.int32)
+                init_fn, step_fn, _ = make_group_launch(runner, run.mesh)
+                rewrite_fn = make_sim_rewriter(runner, run.mesh)
 
-            init_fn, step_fn, _ = make_group_launch(runner, mesh)
-            rewrite_fn = make_sim_rewriter(runner, mesh)
+                # Host-side scheduler state: each active cell's pending grid
+                # index and how many chunk launches its current probe has
+                # consumed.
+                pending: Dict[int, int] = {}
+                chunks_used: Dict[int, int] = {}
+                probes_of: Dict[int, List[RateProbe]] = {ci: [] for ci in cidx}
+                lam_host = np.zeros(Bp, np.float32)
+                seed_host = np.zeros(Bp, np.int32)
+                active: set = set()
 
-            # Host-side scheduler state: each active cell's pending grid
-            # index and how many chunk launches its current probe has
-            # consumed.
-            pending: Dict[int, int] = {}
-            chunks_used: Dict[int, int] = {}
-            probes_of: Dict[int, List[RateProbe]] = {ci: [] for ci in cidx}
-            lam_host = np.zeros(Bp, np.float32)
-            seed_host = np.zeros(Bp, np.int32)
-            active: set = set()
+                def _assign(ci: int, k: int) -> None:
+                    # call_index = attempt: first-attempt probes replay
+                    # the exact sequential fold_seed stream (call_index 0);
+                    # adaptive re-probes draw from the documented re-probe
+                    # stream so doubled-horizon evidence never aliases the
+                    # evidence that failed to decide.
+                    pending[ci] = k
+                    chunks_used[ci] = 0
+                    sl = lane_of[ci]
+                    lam_host[sl] = np.float32(k * steps[ci])
+                    seed_host[sl] = [fold_seed(cells[ci].topo_seed, k,
+                                               attempt[ci], s)
+                                     for s in seeds]
 
-            def _assign(ci: int, k: int) -> None:
-                # call_index = attempt: first-attempt probes replay the
-                # exact sequential fold_seed stream (call_index 0); adaptive
-                # re-probes draw from the documented re-probe stream so
-                # doubled-horizon evidence never aliases the evidence that
-                # failed to decide.
-                pending[ci] = k
-                chunks_used[ci] = 0
-                sl = lane_of[ci]
-                lam_host[sl] = np.float32(k * steps[ci])
-                seed_host[sl] = [fold_seed(cells[ci].topo_seed, k,
-                                           attempt[ci], s)
-                                 for s in seeds]
-
-            resume_here = resumed is not None and g == resumed["group"]
-            if resume_here and resumed["g_launches"] > 0:
-                # Mid-group restore: the carry at the snapshot boundary
-                # plus the lane tables / pending probes exactly as the
-                # killed sweep left them; machines/rows/counters were
-                # restored above.
-                pending = {int(k): v for k, v in resumed["pending"].items()}
-                chunks_used = {int(k): v
-                               for k, v in resumed["chunks_used"].items()}
-                for ci_s, ps in resumed["probes"].items():
-                    probes_of[int(ci_s)] = [rz.probe_restore(p) for p in ps]
-                lam_host = np.array(resumed["lam_host"], np.float32)
-                seed_host = np.array(resumed["seed_host"], np.int32)
-                active = set(resumed["active"])
-                like = jax.eval_shape(init_fn, pp)
-                carry = rt.restore_carry(like, mesh)
-                g_launches = resumed["g_launches"]
-            else:
-                carry = init_fn(pp)
-                park0 = np.zeros(Bp, bool)
-                for ci in cidx:
-                    k = machines[ci].next_rate_index()
-                    if k is None:   # degenerate budget: decided probe-free
-                        rows[ci] = _finish_row(cells[ci], bounds[ci],
-                                               steps[ci], machines[ci], [],
-                                               bucket=bkt)
-                        park0[lane_of[ci]] = True
-                    else:
-                        active.add(ci)
-                        _assign(ci, k)
-                lam_host[B:] = lam_host[B - 1]
-                seed_host[B:] = seed_host[B - 1]
-                park0[B:] = park0[B - 1]
-                if park0.any():
-                    carry = rewrite_fn(pp, jnp.zeros(Bp, bool),
-                                       jnp.asarray(park0), carry)
-                    n_rewrites += 1
-                g_launches = 0
-            if sink is not None and resume_here:
-                from repro.obs import schema
-                sink.write(schema.make_record(
-                    "resume", group=g, chunk=g_launches,
-                    t=g_launches * runner.chunk, n_sims=B, engine="atlas",
-                    ckpt_step=resumed["ckpt_step"],
-                    n_preloaded=sink.n_preloaded))
-
-        first_cache = None        # the step's jit-cache size after launch 1
-        while active:
-            with span("atlas.launch", n=len(active) * S):
-                lam = jnp.asarray(lam_host)
-                keys = jax.vmap(jax.random.PRNGKey)(jnp.asarray(seed_host))
-                if rt is not None:
-                    try:
-                        carry = rt.launch(g, n_launches, step_fn, pp, lam,
-                                          eps, ak, ek, keys, carry)
-                    except Exception:
-                        if sink is not None:
-                            sink.close()
-                        raise
+                resume_here = resumed is not None and g == resumed["group"]
+                if resume_here and resumed["g_launches"] > 0:
+                    # Mid-group restore: the carry at the snapshot boundary
+                    # plus the lane tables / pending probes exactly as the
+                    # killed sweep left them; machines/rows/counters were
+                    # restored above.
+                    pending = {int(k): v
+                               for k, v in resumed["pending"].items()}
+                    chunks_used = {int(k): v
+                                   for k, v in resumed["chunks_used"].items()}
+                    for ci_s, ps in resumed["probes"].items():
+                        probes_of[int(ci_s)] = [rz.probe_restore(p)
+                                                for p in ps]
+                    lam_host = np.array(resumed["lam_host"], np.float32)
+                    seed_host = np.array(resumed["seed_host"], np.int32)
+                    active = set(resumed["active"])
+                    carry = run.restore_carry(init_fn, pp)
+                    g_launches = resumed["g_launches"]
                 else:
-                    carry = step_fn(pp, lam, eps, ak, ek, keys, carry)
-            if first_cache is None:
-                first_cache = int(step_fn._cache_size())
-            n_launches += 1
-            g_launches += 1
-            bucket_launches[bkt] += 1
-            for ci in active:
-                chunks_used[ci] += 1
+                    carry = init_fn(pp)
+                    park0 = np.zeros(Bp, bool)
+                    for ci in cidx:
+                        k = machines[ci].next_rate_index()
+                        if k is None:   # degenerate budget: decided probe-free
+                            rows[ci] = _finish_row(cells[ci], bounds[ci],
+                                                   steps[ci], machines[ci], [],
+                                                   bucket=bkt)
+                            park0[lane_of[ci]] = True
+                        else:
+                            active.add(ci)
+                            _assign(ci, k)
+                    mirror_replicas(B, lam_host, seed_host, park0)
+                    if park0.any():
+                        carry = rewrite_fn(pp, jnp.zeros(Bp, bool),
+                                           jnp.asarray(park0), carry)
+                        n_rewrites += 1
+                    g_launches = 0
+                if resume_here:
+                    run.mark_resume(g, g_launches,
+                                    g_launches * runner.chunk, B)
 
-            # Between-launch readout: the two [Bp] drift leaves only.
-            with span("atlas.readback"):
-                v_leaf, d_leaf = runner.drift_of(carry)
-                verdicts = np.asarray(jax.device_get(v_leaf))
-                decided_at = np.asarray(jax.device_get(d_leaf))
+            first_cache = None    # the step's jit-cache size after launch 1
+            while active:
+                with span("atlas.launch", n=len(active) * S):
+                    lam = jnp.asarray(lam_host)
+                    keys = lane_keys(seed_host)
+                    carry = run.launch(g, n_launches, step_fn, pp, lam,
+                                       batch.eps, batch.ak, batch.ek, keys,
+                                       carry)
+                if first_cache is None:
+                    first_cache = int(step_fn._cache_size())
+                n_launches += 1
+                g_launches += 1
+                bucket_launches[bkt] += 1
+                for ci in active:
+                    chunks_used[ci] += 1
 
-            with span("atlas.harvest") as sp:
-                harvested = 0
-                reset = np.zeros(Bp, bool)
-                park = np.zeros(Bp, bool)
-                changed = False
-                if rt is not None:
-                    dead = rt.dead_hosts(n_launches)
-                    if dead:
-                        # Graceful degradation: park every active cell with
-                        # a lane on a dead host, finish its row from the
+                # Between-launch readout: the two [Bp] drift leaves only.
+                with span("atlas.readback"):
+                    v_leaf, d_leaf = runner.drift_of(carry)
+                    verdicts = np.asarray(jax.device_get(v_leaf))
+                    decided_at = np.asarray(jax.device_get(d_leaf))
+
+                with span("atlas.harvest") as sp:
+                    harvested = 0
+                    reset = np.zeros(Bp, bool)
+                    park = np.zeros(Bp, bool)
+                    changed = False
+                    hit = run.dead_lanes(n_launches, Bp)
+                    if hit is not None:
+                        # Graceful degradation: park every active cell with a
+                        # lane on a dead host and finish its row from the
                         # bracket *at the dropout* (degraded=True, never
-                        # silent), and re-plan the mesh.  The rest of the
-                        # atlas keeps bisecting.
-                        lane_dead = rz.host_lane_mask(Bp, ndev, dead)
-                        per = Bp // ndev
+                        # silent).  The rest of the atlas keeps bisecting.
+                        lane_dead, host = hit
                         for ci in sorted(active):
                             sl = lane_of[ci]
                             if lane_dead[sl].any():
@@ -537,152 +478,130 @@ def sweep_lambda_max(cells: Sequence[AtlasJob], *,
                                 park[sl] = True
                                 rows[ci] = _finish_row(
                                     cells[ci], bounds[ci], steps[ci],
-                                    machines[ci], probes_of[ci],
-                                    degraded=True, bucket=bkt,
-                                    n_requeues=attempt[ci])
-                                hosts = sorted({
-                                    l // per
-                                    for l in range(sl.start, sl.stop)
-                                    if lane_dead[l]})
-                                degraded[ci] = "host_dropout:" + ",".join(
+                                    machines[ci], probes_of[ci], degraded=True,
+                                    bucket=bkt, n_requeues=attempt[ci])
+                                hosts = sorted(set(host[sl][lane_dead[sl]]))
+                                run.degraded[ci] = "host_dropout:" + ",".join(
                                     f"host{h}" for h in hosts)
                                 changed = True
-                        if (recovery is None
-                                or set(dead) != set(recovery.evict)):
-                            from repro.runtime.fault import plan_recovery
-                            recovery = plan_recovery(
-                                ndev, 1, [f"host{h}" for h in dead], [], 1)
-                for ci in sorted(active):
-                    sl = lane_of[ci]
-                    v = verdicts[sl]
-                    # Adaptive horizon: attempt a probes up to
-                    # n_chunks << a launches — verdict latching lives in the
-                    # window config, not T, so a doubled horizon is just
-                    # more chunk launches of the same program.
-                    horizon = n_chunks << attempt[ci]
-                    finished = chunks_used[ci] >= horizon or (
-                        early_stop and bool(np.all(v != VERDICT_UNDECIDED)))
-                    if not finished:
-                        continue
-                    # --- harvest: the exact RateProbe the sequential path
-                    # would have built from run_fleet's finalize metrics.
-                    k = pending[ci]
-                    cell_T = runner.T << attempt[ci]
-                    names = tuple(VERDICT_NAMES[int(x)] for x in v)
-                    sustainable = all(n == "STABLE" for n in names)
-                    d_eff = np.where(v != VERDICT_UNDECIDED,
-                                     decided_at[sl], cell_T)
-                    saved = (int(np.sum(cell_T - d_eff)) if vcfg.freeze
-                             else 0)
-                    probes_of[ci].append(RateProbe(
-                        rate_index=k, call_index=attempt[ci],
-                        lam=k * steps[ci],
-                        sustainable=sustainable, verdicts=names,
-                        decided_at=tuple(int(x) for x in d_eff),
-                        slots_run=S * cell_T - saved, slots_saved=saved,
-                        undecided=(not sustainable
-                                   and "UNSTABLE" not in names)))
-                    harvested += 1
-                    seq_launches += chunks_used[ci]
-                    launch_slots_saved += \
-                        S * (horizon - chunks_used[ci]) * runner.chunk
-                    machines[ci].record(k, sustainable,
-                                        probes_of[ci][-1].undecided)
-                    k2 = machines[ci].next_rate_index()
-                    if k2 is None and (machines[ci].undecided_hi
-                                       or machines[ci].k_lo == 0) \
-                            and attempt[ci] < max_requeues:
-                        # Re-queue (DESIGN.md §13): either the bracket top
-                        # is blocked by UNDECIDED-at-horizon evidence only,
-                        # or the bracket fully collapsed (k_lo == 0: no rate
-                        # proved sustainable).  The collapse case covers the
-                        # low-rate false-UNSTABLE artifact — at rates far
-                        # below capacity the backpressure gradient fills so
-                        # slowly that the whole horizon sits inside the
-                        # transient and the drift + gap tests both latch
-                        # UNSTABLE (paper_grid topo_seeds 5/8/15 at T=4096
-                        # read proven-UNSTABLE at 0.1x their own exact
-                        # bound); genuinely-capacity-0 cells (wireless_grid)
-                        # burn the re-queue ladder and still report 0, which
-                        # the bench asserts.  Restart the whole search from
-                        # the original integer bracket with a doubled chunk
-                        # budget instead of reporting the collapsed bracket.
-                        # The fresh machine replays the deterministic probe
-                        # order; _assign stamps the bumped call_index into
-                        # every fold_seed.
-                        attempt[ci] += 1
-                        n_requeues += 1
-                        machines[ci] = Bisection(k_lo=k_lo0[ci],
-                                                 k_hi=k_hi0[ci],
-                                                 max_calls=max_calls)
+                    for ci in sorted(active):
+                        sl = lane_of[ci]
+                        v = verdicts[sl]
+                        # Adaptive horizon: attempt a probes up to
+                        # n_chunks << a launches — verdict latching lives in
+                        # the window config, not T, so a doubled horizon is
+                        # just more chunk launches of the same program.
+                        horizon = n_chunks << attempt[ci]
+                        finished = chunks_used[ci] >= horizon or (
+                            early_stop
+                            and bool(np.all(v != VERDICT_UNDECIDED)))
+                        if not finished:
+                            continue
+                        # --- harvest: the exact RateProbe the sequential path
+                        # would have built from run_fleet's finalize metrics.
+                        k = pending[ci]
+                        cell_T = runner.T << attempt[ci]
+                        names = tuple(VERDICT_NAMES[int(x)] for x in v)
+                        sustainable = all(n == "STABLE" for n in names)
+                        d_eff = np.where(v != VERDICT_UNDECIDED,
+                                         decided_at[sl], cell_T)
+                        saved = (int(np.sum(cell_T - d_eff)) if vcfg.freeze
+                                 else 0)
+                        probes_of[ci].append(RateProbe(
+                            rate_index=k, call_index=attempt[ci],
+                            lam=k * steps[ci],
+                            sustainable=sustainable, verdicts=names,
+                            decided_at=tuple(int(x) for x in d_eff),
+                            slots_run=S * cell_T - saved, slots_saved=saved,
+                            undecided=(not sustainable
+                                       and "UNSTABLE" not in names)))
+                        harvested += 1
+                        seq_launches += chunks_used[ci]
+                        launch_slots_saved += \
+                            S * (horizon - chunks_used[ci]) * runner.chunk
+                        machines[ci].record(k, sustainable,
+                                            probes_of[ci][-1].undecided)
                         k2 = machines[ci].next_rate_index()
-                    if k2 is None:
-                        active.discard(ci)
-                        park[sl] = True
-                        rows[ci] = _finish_row(cells[ci], bounds[ci],
-                                               steps[ci], machines[ci],
-                                               probes_of[ci], bucket=bkt,
-                                               n_requeues=attempt[ci])
-                    else:
-                        reset[sl] = True
-                        _assign(ci, k2)
-                    changed = True
-                sp.n = harvested
-            if changed and active:
-                # Replicas mirror the last real lane's fate so they stay
-                # bit-synchronized with (or parked alongside) their source.
-                # No rewrite once the group drains: nothing launches again.
-                with span("atlas.rewrite", n=int((reset | park)[:B].sum())):
-                    reset[B:] = reset[B - 1]
-                    park[B:] = park[B - 1]
-                    lam_host[B:] = lam_host[B - 1]
-                    seed_host[B:] = seed_host[B - 1]
-                    carry = rewrite_fn(pp, jnp.asarray(reset),
-                                       jnp.asarray(park), carry)
-                n_rewrites += 1
-            if sink is not None:
-                with span("atlas.record"):
-                    sink.write(_atlas_record(
-                        g, bkt, n_requeues, g_launches, runner.chunk, B,
-                        cells, cidx, active, machines, steps, bounds,
-                        probes_of, verdicts[:B]))
+                        if k2 is None and (machines[ci].undecided_hi
+                                           or machines[ci].k_lo == 0) \
+                                and attempt[ci] < max_requeues:
+                            # Re-queue (DESIGN.md §13): either the bracket
+                            # top is blocked by UNDECIDED-at-horizon evidence
+                            # only, or the bracket fully collapsed (k_lo == 0:
+                            # no rate proved sustainable).  The collapse case
+                            # covers the low-rate false-UNSTABLE artifact — at
+                            # rates far below capacity the backpressure
+                            # gradient fills so slowly that the whole horizon
+                            # sits inside the transient and the drift + gap
+                            # tests both latch UNSTABLE (paper_grid topo_seeds
+                            # 5/8/15 at T=4096 read proven-UNSTABLE at 0.1x
+                            # their own exact bound); genuinely-capacity-0
+                            # cells (wireless_grid) burn the re-queue ladder
+                            # and still report 0, which the bench asserts.
+                            # Restart the whole search from the original
+                            # integer bracket with a doubled chunk budget
+                            # instead of reporting the collapsed bracket.  The
+                            # fresh machine replays the deterministic probe
+                            # order; _assign stamps the bumped call_index into
+                            # every fold_seed.
+                            attempt[ci] += 1
+                            n_requeues += 1
+                            machines[ci] = Bisection(k_lo=k_lo0[ci],
+                                                     k_hi=k_hi0[ci],
+                                                     max_calls=max_calls)
+                            k2 = machines[ci].next_rate_index()
+                        if k2 is None:
+                            active.discard(ci)
+                            park[sl] = True
+                            rows[ci] = _finish_row(cells[ci], bounds[ci],
+                                                   steps[ci], machines[ci],
+                                                   probes_of[ci], bucket=bkt,
+                                                   n_requeues=attempt[ci])
+                        else:
+                            reset[sl] = True
+                            _assign(ci, k2)
+                        changed = True
+                    sp.n = harvested
+                if changed and active:
+                    # Replicas mirror the last real lane's fate so they
+                    # stay bit-synchronized with (or parked alongside) their
+                    # source.  No rewrite once the group drains: nothing
+                    # launches again.
+                    with span("atlas.rewrite",
+                              n=int((reset | park)[:B].sum())):
+                        mirror_replicas(B, reset, park, lam_host, seed_host)
+                        carry = rewrite_fn(pp, jnp.asarray(reset),
+                                           jnp.asarray(park), carry)
+                    n_rewrites += 1
+                if run.sink is not None:
+                    with span("atlas.record"):
+                        run.sink.write(_atlas_record(
+                            g, bkt, n_requeues, g_launches, runner.chunk, B,
+                            cells, cidx, active, machines, steps, bounds,
+                            probes_of, verdicts[:B]))
 
-            if rt is not None and rt.should_snapshot(n_launches):
-                with span("atlas.snapshot"):
-                    rt.snapshot(n_launches, carry, _atlas_extra(
-                        g, g_launches, n_launches, seq_launches, n_rewrites,
-                        launch_slots_saved, n_step_compiles, machines, rows,
-                        pending, chunks_used, probes_of, cidx, lam_host,
-                        seed_host, active, degraded, recovery, attempt,
-                        n_requeues, bucket_launches))
-            if rt is not None:
-                try:
-                    rt.maybe_preempt(n_launches)
-                except Exception:
-                    if sink is not None:
-                        sink.close()
-                    raise
-
-        if first_cache is not None:
-            # The unit's program, plus every trace its launches added after
-            # the first: a retrace inside the loop counts, a program that an
-            # earlier sweep in this process compiled counts once, as here.
-            n_step_compiles += 1 + int(step_fn._cache_size()) - first_cache
-
-        if rt is not None and rt.should_snapshot(n_launches):
-            # Unit-end marker: empty carry, cursor at the next unit's
-            # start — a resume here re-enters the fresh path with the
-            # restored machines re-pulling the same deterministic grid.
-            with span("atlas.snapshot"):
-                rt.snapshot(n_launches, (), _atlas_extra(
-                    g + 1, 0, n_launches, seq_launches, n_rewrites,
+                run.boundary(n_launches, carry, lambda: _atlas_extra(
+                    g, g_launches, n_launches, seq_launches, n_rewrites,
                     launch_slots_saved, n_step_compiles, machines, rows,
-                    {}, {}, {ci: [] for ci in cidx}, cidx, lam_host,
-                    seed_host, set(), degraded, recovery, attempt,
-                    n_requeues, bucket_launches))
+                    pending, chunks_used, probes_of, cidx, lam_host,
+                    seed_host, active, attempt, n_requeues, bucket_launches,
+                    run.cursor_state()))
 
-    if sink is not None:
-        sink.close()
+            if first_cache is not None:
+                # The unit's program, plus every trace its launches added
+                # after the first: a retrace inside the loop counts, a
+                # program that an earlier sweep in this process compiled
+                # counts once, as here.
+                n_step_compiles += 1 + int(step_fn._cache_size()) - first_cache
+
+            # Unit-end marker: empty carry, cursor at the next unit's start —
+            # a resume here re-enters the fresh path with the restored
+            # machines re-pulling the same deterministic grid.
+            run.snapshot(n_launches, (), lambda: _atlas_extra(
+                g + 1, 0, n_launches, seq_launches, n_rewrites,
+                launch_slots_saved, n_step_compiles, machines, rows, {}, {},
+                {ci: [] for ci in cidx}, cidx, lam_host, seed_host, set(),
+                attempt, n_requeues, bucket_launches, run.cursor_state()))
     done_rows = [r for r in rows if r is not None]
     assert len(done_rows) == len(cells)
     n_bucket_cells: Dict[int, int] = {}
@@ -698,11 +617,10 @@ def sweep_lambda_max(cells: Sequence[AtlasJob], *,
         slots_saved=sum(r.slots_saved for r in done_rows),
         launch_slots_saved=launch_slots_saved,
         dims=dims, T=eff_T, chunk=eff_chunk,
-        stream_records=sink.records if sink is not None else [],
-        resumed_from=(resumed["n_launches"] if resumed is not None
-                      else None),
-        degraded=degraded, recovery_plan=recovery,
-        n_fault_retries=rt.n_retries if rt is not None else 0,
+        stream_records=run.records,
+        resumed_from=run.resumed_from,
+        degraded=run.degraded, recovery_plan=run.recovery,
+        n_fault_retries=run.n_retries,
         bucket_dims=list(bucket_dims),
         bucket_cells=n_bucket_cells,
         bucket_launches=dict(bucket_launches),
@@ -734,15 +652,16 @@ def sweep_policy_surface(families: Sequence[str],
 def _atlas_extra(group, g_launches, n_launches, seq_launches, n_rewrites,
                  launch_slots_saved, n_step_compiles, machines, rows,
                  pending, chunks_used, probes_of, cidx, lam_host,
-                 seed_host, active, degraded, recovery, attempt,
-                 n_requeues, bucket_launches) -> dict:
+                 seed_host, active, attempt, n_requeues, bucket_launches,
+                 state) -> dict:
     """JSON-serializable sweep cursor for one checkpoint (DESIGN.md §12).
 
     Machines, finished rows and attempt counters are global (every cell,
     so already-finished units restore without replay); the lane tables
     and pending probes are the current (group × bucket) unit's only.
     ``group`` is the unit cursor — the bucket identity is implied by the
-    deterministic unit enumeration."""
+    deterministic unit enumeration.  ``state`` is the run's degradation
+    state (`driver.Run.cursor_state`)."""
     from repro.runtime import resilience as rz
 
     return {
@@ -766,8 +685,7 @@ def _atlas_extra(group, g_launches, n_launches, seq_launches, n_rewrites,
         "lam_host": [float(x) for x in lam_host],
         "seed_host": [int(x) for x in seed_host],
         "active": sorted(int(ci) for ci in active),
-        "degraded": {str(ci): v for ci, v in degraded.items()},
-        "recovery": rz.plan_state(recovery),
+        **state,
     }
 
 

@@ -42,6 +42,7 @@ import numpy as np
 
 from repro.core.graph import ComputeProblem
 from repro.core.queues import StaticProblem, max_node_degree
+from .scenarios import arrival_code, event_code, get_scenario
 
 
 @jax.tree_util.register_pytree_node_class
@@ -267,5 +268,103 @@ def stack_problems(problems: Sequence[ComputeProblem],
     then runs all instances inside a single compiled program.
     """
     dims = dims or PadDims.of(problems)
-    padded = [pad_problem(p, dims) for p in problems]
+    return _stack([pad_problem(p, dims) for p in problems])
+
+
+def _stack(padded: Sequence[PaddedProblem]) -> PaddedProblem:
     return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *padded)
+
+
+# -- the lane batch ---------------------------------------------------------
+# Every engine lays its lanes out one way (DESIGN.md §4): a job's lanes are
+# contiguous, and the batch is padded to a multiple of the mesh width by
+# repeating the last real lane.  Replicas are never read back: they mirror
+# every host-side rewrite of their source (`mirror_replicas`), so they stay
+# bit-synchronised with it and never hold a launch open on their own.
+
+
+def pad_topologies(jobs: Sequence, dims: PadDims | None = None,
+                   n_buckets: int = 1
+                   ) -> Tuple[Dict[tuple, PaddedProblem], Dict[tuple, int],
+                              List[PadDims]]:
+    """Build each distinct ``(scenario, topo_seed)`` of ``jobs`` once and
+    pad it to its bucket's dims.
+
+    ``dims`` forces one bucket of those dims; otherwise `make_buckets`
+    cuts ``n_buckets`` size buckets (one: the `PadDims.of` hull of every
+    topology).  Returns ``(padded_of, bucket_of, bucket_dims)``, the first
+    two keyed by ``(scenario, topo_seed)``."""
+    problem_of: Dict[tuple, ComputeProblem] = {}
+    for job in jobs:
+        k = (job.scenario, job.topo_seed)
+        if k not in problem_of:
+            problem_of[k] = get_scenario(job.scenario).build(job.topo_seed)
+    keys = list(problem_of)
+    if dims is not None:
+        bucket_dims, assignment = [dims], [0] * len(keys)
+    else:
+        bucket_dims, assignment = make_buckets(
+            [problem_of[k] for k in keys], n_buckets)
+    bucket_of = dict(zip(keys, assignment))
+    padded_of = {k: pad_problem(problem_of[k], bucket_dims[bucket_of[k]])
+                 for k in keys}
+    return padded_of, bucket_of, bucket_dims
+
+
+@dataclasses.dataclass
+class LaneBatch:
+    """One launch unit's lanes as device arrays: ``B`` real lanes padded to
+    ``Bp``, a multiple of the mesh width.  ``ak`` is None where a trace
+    supersedes the arrival models (serving); ``lam`` and ``keys`` are None
+    where the engine rewrites them every launch (the atlas)."""
+
+    B: int
+    Bp: int
+    pp: PaddedProblem
+    eps: jax.Array
+    ek: jax.Array
+    ak: jax.Array | None = None
+    lam: jax.Array | None = None
+    keys: jax.Array | None = None
+
+    @property
+    def step_args(self) -> tuple:
+        """The chunk step's arguments before the carry."""
+        return tuple(a for a in (self.pp, self.lam, self.eps, self.ak,
+                                 self.ek, self.keys) if a is not None)
+
+
+def lane_batch(jobs: Sequence, padded_of: Dict[tuple, PaddedProblem],
+               ndev: int, *, arrivals: bool = True,
+               rates: bool = True) -> LaneBatch:
+    """Lay out one lane per job record (``scenario``, ``topo_seed``,
+    ``eps_b``, and with ``rates`` also ``lam`` and ``seed``), mesh-padded
+    to a multiple of ``ndev`` by repeating the last lane."""
+    B = len(jobs)
+    Bp = -(-B // ndev) * ndev
+    lanes = list(jobs) + [jobs[-1]] * (Bp - B)
+    scen = [get_scenario(j.scenario) for j in lanes]
+    pp = _stack([padded_of[(j.scenario, j.topo_seed)] for j in lanes])
+    lam = (jnp.array([j.lam for j in lanes], jnp.float32) if rates
+           else None)
+    eps = jnp.array([j.eps_b for j in lanes], jnp.float32)
+    ak = (jnp.array([arrival_code(s.arrival) for s in scen], jnp.int32)
+          if arrivals else None)
+    ek = jnp.array([event_code(s.events) for s in scen], jnp.int32)
+    keys = lane_keys([j.seed for j in lanes]) if rates else None
+    return LaneBatch(B=B, Bp=Bp, pp=pp, eps=eps, ek=ek, ak=ak, lam=lam,
+                     keys=keys)
+
+
+def lane_keys(seeds) -> jax.Array:
+    """[B, 2] PRNG keys, one vmapped derivation for all lanes.  int32 keeps
+    negative seeds legal (uint32 would overflow at the host conversion);
+    PRNGKey folds them identically either way."""
+    return jax.vmap(jax.random.PRNGKey)(jnp.asarray(seeds, jnp.int32))
+
+
+def mirror_replicas(B: int, *tables: np.ndarray) -> None:
+    """Copy lane ``B - 1`` into the mesh-padding replicas ``B:`` of each
+    [Bp] host table, in place."""
+    for t in tables:
+        t[B:] = t[B - 1]

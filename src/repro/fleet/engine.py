@@ -3,14 +3,14 @@
 Jobs = (scenario x policy x rate x seed) tuples.  The engine
 
   1. builds each job's topology once, pads all of them to fleet-wide maxima
-     (`batching.stack_problems`), and
+     (`batching.pad_topologies`, laid out by `batching.lane_batch`), and
   2. groups jobs by `PolicyConfig` — the only axis that changes Python-level
      control flow in `slot_step`, hence the only axis that forces a separate
      compiled program.  Everything else (topology, arrival model, event
      model, rate, seed) is traced data: heterogeneous scenarios ride one
      program via padded constants and `lax.switch` over model codes.
-  3. runs each group as a short Python loop of `jax.jit(shard_map(vmap(
-     chunk_step)))` launches over the (host-platform) device mesh — each
+  3. runs each group as a short Python loop (`driver.run_groups`) of
+     `jit(shard_map(vmap(chunk_step)))` launches over the device mesh — each
      launch advances one chunk of the time scan with the carry *donated*
      back into the next launch (`make_group_launch`), and per-slot *online*
      metric accumulators ride the carry — no [T]-shaped trace is ever
@@ -46,14 +46,14 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.obs.spans import span, spanned
-from repro.runtime.fault import RecoveryPlan, plan_recovery
-
+from repro.runtime.fault import RecoveryPlan
 from repro.core.graph import ComputeProblem
 from repro.core.policies import PolicyConfig, slot_step
 from repro.core.queues import (DriftStats, VERDICT_NAMES, VERDICT_STABLE,
                                VERDICT_UNDECIDED, VERDICT_UNSTABLE,
                                drift_verdict_update, init_state, kahan_add)
-from .batching import PadDims, PaddedProblem, pad_problem
+from .batching import PadDims, PaddedProblem, pad_problem, pad_topologies
+from .driver import Run, run_groups
 from .scenarios import (ARRIVAL_MODELS, ARRIVAL_MODEL_ORDER, EVENT_MODELS,
                         EVENT_MODEL_ORDER, ModState, arrival_code, event_code,
                         get_scenario)
@@ -575,8 +575,9 @@ def run_fleet(jobs: Sequence[FleetJob], T: int, chunk: int = 1024,
               resilience=None) -> FleetResult:
     """Run the whole sweep, one compiled program set per policy group.
 
-    Each group runs as a Python-level loop of `n_chunks` launches of one
-    `jit(shard_map(vmap(chunk_step)))` with the scan carry *donated*
+    Each group runs as a Python-level loop (`driver.run_groups`) of
+    `n_chunks` launches of one `jit(shard_map(vmap(chunk_step)))` with
+    the scan carry *donated*
     between launches (`make_group_launch`), so arbitrarily long horizons
     keep a single in-place copy of the fleet state.  `memory_stats=True`
     additionally attaches the XLA memory analysis of the largest group's
@@ -611,236 +612,40 @@ def run_fleet(jobs: Sequence[FleetJob], T: int, chunk: int = 1024,
     With `repro.obs.spans` recording, the call is one ``fleet.job`` span
     holding ``fleet.build``, and per group ``fleet.group_setup``, one
     ``fleet.launch`` per chunk launch, ``fleet.readout`` per early-stop
-    verdict readback and ``fleet.finalize``.
+    verdict readback and ``fleet.finalize``; ``fleet.snapshot`` per
+    checkpoint written.
     """
     jobs = list(jobs)
-    stream = stream or stream_log is not None or stream_path is not None
     vcfg = resolve_verdict(verdict, early_stop)
-    devices = list(devices or jax.devices())
-    ndev = len(devices)
-    mesh = Mesh(np.array(devices), ("fleet",))
-
-    # Build and pad every distinct topology once; jobs share by reference.
     with span("fleet.build"):
-        problem_of: Dict[tuple, ComputeProblem] = {}
-        for job in jobs:
-            k = (job.scenario, job.topo_seed)
-            if k not in problem_of:
-                problem_of[k] = get_scenario(job.scenario).build(
-                    job.topo_seed)
-        dims = dims or PadDims.of(list(problem_of.values()))
-        padded_of = {k: pad_problem(p, dims) for k, p in problem_of.items()}
-
+        padded_of, _, (dims,) = pad_topologies(jobs, dims)
     groups: Dict[tuple, List[int]] = {}
     for i, job in enumerate(jobs):
         groups.setdefault(_policy_group_key(job), []).append(i)
 
-    rt = resumed = None
-    if resilience is not None:
-        from repro.runtime.resilience import (host_lane_mask as
-                                              _host_lane_mask,
-                                              maybe_resilient)
-        rt = maybe_resilient(resilience, "fleet", jobs=tuple(jobs), T=T,
-                             chunk=chunk, window=window, verdict=vcfg,
-                             early_stop=early_stop, dims=dims, ndev=ndev)
-        resumed = rt.resumed
-
-    metrics: List[Dict[str, float] | None] = [None] * len(jobs)
-    eff_T = eff_win = 0
-    launch_saved = 0
-    glaunch = 0                    # launches completed, across groups — the
-                                   # checkpoint step / fault-plane clock
-    degraded: Dict[int, str] = {}
-    recovery = None
-    mem: Dict[str, float] | None = None
-    mem_B = -1
-    sink = None
-    if stream:
-        from repro.obs.emitter import StreamSink
-        sink = StreamSink(path=stream_path, log=stream_log,
-                          append=resumed is not None)
-    if resumed is not None:
-        from repro.runtime.resilience import metrics_restore, plan_restore
-        for i, m in enumerate(metrics_restore(resumed["metrics"])):
-            if m is not None:
-                metrics[i] = m
-        launch_saved = resumed["launch_saved"]
-        glaunch = resumed["global_launch"]
-        degraded = {int(k): v for k, v in resumed["degraded"].items()}
-        recovery = plan_restore(resumed["recovery"])
-    try:
-        for g, (gkey, idxs) in enumerate(groups.items()):
-            cfg = jobs[idxs[0]].policy_config()
-            runner = make_stream_runner(cfg, T, chunk=chunk, window=window,
-                                        verdict=vcfg)
-            eff_T, eff_win = runner.T, runner.window
-            if resumed is not None and g < resumed["group"]:
-                continue          # finished pre-kill: metrics restored above
-
-            # Per-group host work is hoisted to exactly here — one batch of
-            # device constants per group, built *before* the chunk loop.  Pad
-            # the group batch to a multiple of the mesh size by repeating the
-            # last job; replicas are dropped when results are scattered back.
-            with span("fleet.group_setup"):
-                B = len(idxs)
-                Bp = -(-B // ndev) * ndev
-                padded_idxs = idxs + [idxs[-1]] * (Bp - B)
-                pp = jax.tree_util.tree_map(
-                    lambda *xs: jnp.stack(xs),
-                    *[padded_of[(jobs[i].scenario, jobs[i].topo_seed)]
-                      for i in padded_idxs])
-                lam = jnp.array([jobs[i].lam for i in padded_idxs],
-                                jnp.float32)
-                eps = jnp.array([jobs[i].eps_b for i in padded_idxs],
-                                jnp.float32)
-                ak = jnp.array([arrival_code(
-                    get_scenario(jobs[i].scenario).arrival)
-                    for i in padded_idxs], jnp.int32)
-                ek = jnp.array([event_code(
-                    get_scenario(jobs[i].scenario).events)
-                    for i in padded_idxs], jnp.int32)
-                # One vmapped derivation instead of B host-side PRNGKey
-                # calls.  int32 keeps negative seeds legal (uint32 would
-                # overflow at the host conversion); PRNGKey folds them
-                # identically either way.
-                keys = jax.vmap(jax.random.PRNGKey)(
-                    jnp.array([jobs[i].seed for i in padded_idxs],
-                              jnp.int32))
-
-                init_fn, step_fn, fin_fn = make_group_launch(runner, mesh)
-                resume_here = resumed is not None and g == resumed["group"]
-                if not resume_here:
-                    carry = init_fn(pp)
-            emitter = None
-            try:
-                if sink is not None:
-                    from repro.obs.emitter import ChunkEmitter
-                    emitter = ChunkEmitter("fleet", group=g, n_real=B,
-                                           runner=runner, mesh=mesh,
-                                           sink=sink)
-                launched = 0
-                if resume_here:
-                    launched = resumed["launched"]
-                    if launched > 0:
-                        # Bit-exact restore of the donated carry at the
-                        # snapshot boundary; lam/eps/keys/... above were
-                        # rebuilt deterministically from the job list.
-                        like = jax.eval_shape(init_fn, pp)
-                        carry = rt.restore_carry(like, mesh)
-                    else:
-                        carry = init_fn(pp)
-                    if emitter is not None and launched > 0:
-                        # The snapshot probe is derivable from the carry:
-                        # runner.probe is pure pytree indexing.
-                        emitter.restore_clock(
-                            launched, {k: np.asarray(v) for k, v in
-                                       runner.probe(carry).items()})
-                    if sink is not None:
-                        from repro.obs import schema
-                        sink.write(schema.make_record(
-                            "resume", group=g, chunk=launched,
-                            t=launched * runner.chunk, n_sims=B,
-                            engine="fleet",
-                            ckpt_step=resumed["ckpt_step"],
-                            n_preloaded=sink.n_preloaded))
-                while launched < runner.n_chunks:
-                    with span("fleet.launch"):
-                        if rt is not None:
-                            carry = rt.launch(g, glaunch, step_fn, pp, lam,
-                                              eps, ak, ek, keys, carry)
-                        else:
-                            carry = step_fn(pp, lam, eps, ak, ek, keys,
-                                            carry)
-                    launched += 1
-                    glaunch += 1
-                    if emitter is not None:
-                        # Dispatch the chunk-boundary telemetry probe
-                        # *before* the next launch donates these carry
-                        # buffers (DESIGN.md §11); non-blocking — records
-                        # assemble on the callback thread.
-                        emitter.emit(runner.probe(carry))
-                    if rt is not None:
-                        dead = rt.dead_hosts(glaunch)
-                        if dead:
-                            lane_dead = _host_lane_mask(Bp, ndev, dead)
-                            fresh = [l for l in range(B) if lane_dead[l]
-                                     and idxs[l] not in degraded]
-                            if fresh:
-                                # Park the dead lanes: their verdict leaf
-                                # is forced UNSTABLE (bit-frozen under
-                                # early_stop), their jobs flagged degraded.
-                                carry = make_sim_rewriter(runner, mesh)(
-                                    pp, jnp.zeros(Bp, bool),
-                                    jnp.asarray(lane_dead), carry)
-                                per = Bp // ndev
-                                for l in fresh:
-                                    degraded[idxs[l]] = \
-                                        f"host_dropout:host{l // per}"
-                                recovery = plan_recovery(
-                                    ndev, 1,
-                                    [f"host{h}" for h in dead], [], 1)
-                        if rt.should_snapshot(glaunch):
-                            from repro.runtime.resilience import plan_state
-                            rt.snapshot(glaunch, carry, {
-                                "group": g, "launched": launched,
-                                "global_launch": glaunch,
-                                "metrics": metrics,
-                                "launch_saved": launch_saved,
-                                "degraded": {str(k): v
-                                             for k, v in degraded.items()},
-                                "recovery": plan_state(recovery)})
-                        # After the snapshot: a simulated SIGTERM here
-                        # leaves a durable, bit-exact resume point.
-                        rt.maybe_preempt(glaunch)
-                    if early_stop and launched < runner.n_chunks:
-                        # Between-chunk readout of the [Bp] int32 verdict
-                        # leaf — the mid-run readout the donated-carry
-                        # structure permits.  All sims (mesh-padding
-                        # replicas mirror a real job) decided => the
-                        # remaining chunks would only shuffle frozen bits;
-                        # stop dispatching them.
-                        with span("fleet.readout"):
-                            v = np.asarray(
-                                jax.device_get(runner.verdict_of(carry)))
-                        if np.all(v != VERDICT_UNDECIDED):
-                            break
-                launch_saved += (len(idxs) * (runner.n_chunks - launched)
-                                 * runner.chunk)
-                if memory_stats and Bp > mem_B:
-                    m = _memory_analysis(step_fn,
-                                         (pp, lam, eps, ak, ek, keys, carry))
-                    if m is not None:
-                        mem, mem_B = m, Bp
-                with span("fleet.finalize"):
-                    out = jax.device_get(fin_fn(lam, eps, carry))
-                    for j, i in enumerate(idxs):
-                        metrics[i] = {k: float(v[j]) for k, v in out.items()}
-            finally:
-                if emitter is not None:
-                    emitter.close()   # flush in-flight records, also when
-                                      # a fault/preemption propagates
-            if rt is not None:
-                from repro.runtime.resilience import plan_state
-                # Group-boundary marker: a kill between groups resumes at
-                # g+1 with the finished metrics, never re-running group g.
-                rt.snapshot(glaunch, (), {
-                    "group": g + 1, "launched": 0, "global_launch": glaunch,
-                    "metrics": metrics, "launch_saved": launch_saved,
-                    "degraded": {str(k): v for k, v in degraded.items()},
-                    "recovery": plan_state(recovery)})
-    finally:
-        if sink is not None:
-            sink.close()
+    with Run("fleet", devices, resilience, stream, stream_log, stream_path,
+             jobs=tuple(jobs), T=T, chunk=chunk, window=window,
+             verdict=vcfg, early_stop=early_stop, dims=dims) as run:
+        totals = {"launch_saved": (run.resumed["launch_saved"]
+                                   if run.resumed is not None else 0)}
+        metrics, eff_T, eff_win, mem = run_groups(
+            run, jobs, list(groups.values()), padded_of,
+            make_runner=lambda job: make_stream_runner(
+                job.policy_config(), T, chunk=chunk, window=window,
+                verdict=vcfg),
+            make_launch=make_group_launch,
+            probe=lambda runner, mesh: runner.probe,
+            park=make_sim_rewriter,
+            early_stop=early_stop,
+            memory=_memory_analysis if memory_stats else None,
+            totals=totals)
     return FleetResult(jobs=jobs, metrics=metrics, n_programs=len(groups),
                        n_sims=len(jobs), dims=dims, T=eff_T, window=eff_win,
                        memory_stats=mem,
                        slots_saved=int(sum(m["slots_saved"]
                                            for m in metrics)),
-                       launch_slots_saved=launch_saved,
-                       stream_records=sink.records if sink is not None
-                       else [],
-                       resumed_from=(resumed["ckpt_step"]
-                                     if resumed is not None else None),
-                       degraded=degraded, recovery_plan=recovery,
-                       n_fault_retries=(rt.n_retries if rt is not None
-                                        else 0))
+                       launch_slots_saved=totals["launch_saved"],
+                       stream_records=run.records,
+                       resumed_from=run.resumed_from,
+                       degraded=run.degraded, recovery_plan=run.recovery,
+                       n_fault_retries=run.n_retries)

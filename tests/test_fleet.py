@@ -20,11 +20,12 @@ from repro.fleet import (FleetJob, ModState, PadDims, exact_lam_star,
                          make_stream_runner, pad_problem, policy_bound,
                          policy_bound_exact, run_fleet, stack_problems,
                          stream_simulate, sweep_jobs)
+from repro.fleet.batching import lane_batch, mirror_replicas, pad_topologies
 from repro.fleet.scenarios import (ARRIVAL_MODELS, EVENT_MODELS,
                                    EVENT_MODEL_ORDER, GE_BAD_SCALE,
                                    GE_COMP_P_DU, GE_COMP_P_UD, GE_P_BG,
                                    GE_P_GB, MMPP_P_OFF_ON, MMPP_P_ON_OFF,
-                                   SCENARIOS)
+                                   SCENARIOS, event_code)
 
 TRI = ComputeProblem(triangle_graph(4.0), s1=0, s2=1, dest=2,
                      comp_nodes=(2,), comp_caps=(2.0,))
@@ -120,6 +121,42 @@ class TestPaddedBatching:
         out = jax.vmap(run_one)(batch, arr, keys)
         assert out.shape == (3,)
         assert np.all(np.asarray(out) >= 0.0)
+
+    @pytest.mark.parametrize("B,ndev", [(3, 1), (3, 4), (5, 2)])
+    def test_lane_batch_pads_the_mesh_with_the_last_lane(self, B, ndev):
+        """Every leaf and per-lane constant of a mesh-padding replica is
+        the last real lane's; `mirror_replicas` keeps host tables so."""
+        scen = ["paper_grid", "ring", "ge_grid", "paper_grid", "ring"]
+        jobs = [FleetJob(scenario=scen[i], lam=1.0 + i, seed=2 ** 31 - 1 - i,
+                         eps_b=0.01 * (i + 1)) for i in range(B)]
+        padded_of, _, _ = pad_topologies(jobs)
+        batch = lane_batch(jobs, padded_of, ndev)
+        Bp = -(-B // ndev) * ndev
+        assert (batch.B, batch.Bp) == (B, Bp)
+        want = jobs + [jobs[-1]] * (Bp - B)
+        np.testing.assert_array_equal(
+            np.asarray(batch.lam), np.float32([j.lam for j in want]))
+        np.testing.assert_array_equal(
+            np.asarray(batch.eps), np.float32([j.eps_b for j in want]))
+        np.testing.assert_array_equal(
+            np.asarray(batch.ek),
+            [event_code(get_scenario(j.scenario).events) for j in want])
+        np.testing.assert_array_equal(
+            np.asarray(batch.keys),
+            np.stack([jax.random.PRNGKey(j.seed) for j in want]))
+        for lane, j in enumerate(want):
+            one = padded_of[(j.scenario, j.topo_seed)]
+            for got, ref in zip(jax.tree_util.tree_leaves(batch.pp),
+                                jax.tree_util.tree_leaves(one)):
+                np.testing.assert_array_equal(np.asarray(got)[lane], ref)
+        assert len(batch.step_args) == 6
+        bare = lane_batch(jobs, padded_of, ndev, arrivals=False, rates=False)
+        assert [a is b for a, b in zip(bare.step_args,
+                                       (bare.pp, bare.eps, bare.ek))] == \
+            [True] * 3
+        table = np.arange(Bp)
+        mirror_replicas(B, table)
+        assert table.tolist() == list(range(B)) + [B - 1] * (Bp - B)
 
     def test_masked_edge_carries_no_flow(self):
         """Zeroing an edge's mask is equivalent to removing the link."""

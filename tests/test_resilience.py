@@ -306,51 +306,90 @@ class TestAtlasResume:
 # Graceful degradation: host dropout parks lanes, reports, never aborts
 # ---------------------------------------------------------------------------
 
+# One small run of each engine, and its per-job results (rows for the atlas).
+ENGINES = {
+    "fleet": lambda **kw: run_fleet(FLEET_JOBS, T=512, chunk=128, **kw),
+    "serving": lambda **kw: run_serving(SERVING_JOBS, T=512, chunk=128,
+                                        **kw),
+    "atlas": lambda **kw: sweep_lambda_max(ATLAS_CELLS, **ATLAS_KW, **kw),
+}
+
+
+def _per_job(res):
+    return res.rows if hasattr(res, "rows") else res.metrics
+
+
+def _job_equal(a, b):
+    if isinstance(a, dict):
+        _metrics_equal([a], [b])
+    else:
+        assert a == b              # AtlasRow: the whole lambda_max search
+
+
 @pytest.mark.fleet_smoke
 class TestDegradation:
-    def test_atlas_host_dropout_degrades_not_aborts(self):
-        base = sweep_lambda_max(ATLAS_CELLS, **ATLAS_KW)
-        res = sweep_lambda_max(
-            ATLAS_CELLS, **ATLAS_KW,
-            resilience=ResilienceConfig(
-                fault_plane=FaultPlane.host_dropout(host=0, at_launch=2)))
-        assert len(res.rows) == len(ATLAS_CELLS)
+    @pytest.mark.parametrize("engine", list(ENGINES))
+    def test_host_dropout_degrades_not_aborts(self, engine):
+        """A dead host flags its jobs and re-plans the mesh, and the run
+        finishes.  The fleet and the atlas park the dead lanes; serving
+        only flags them, so its lanes run on to the same metrics."""
+        run = ENGINES[engine]
+        base = run()
+        res = run(resilience=ResilienceConfig(
+            fault_plane=FaultPlane.host_dropout(host=0, at_launch=2)))
         assert res.degraded, "dropout was silent"
-        for ci, why in res.degraded.items():
+        for why in res.degraded.values():
             assert why.startswith("host_dropout:")
-        flagged = {i for i, r in enumerate(res.rows) if r.degraded}
-        assert flagged == set(res.degraded)
-        # unaffected cells keep bit-identical brackets
-        for i, (r0, r1) in enumerate(zip(base.rows, res.rows)):
-            if i not in flagged:
-                assert r0 == r1
+        assert len(_per_job(res)) == len(_per_job(base))
+        if engine == "atlas":
+            flagged = {i for i, r in enumerate(res.rows) if r.degraded}
+            assert flagged == set(res.degraded)
+        for i, (a, b) in enumerate(zip(_per_job(base), _per_job(res))):
+            if engine == "serving" or i not in res.degraded:
+                _job_equal(a, b)
         assert res.recovery_plan is not None
         assert res.recovery_plan.action == "remesh"
         assert res.recovery_plan.evict == ("host0",)
 
-    def test_fleet_host_dropout_degrades_not_aborts(self):
-        base = run_fleet(FLEET_JOBS, T=512, chunk=128)
-        res = run_fleet(FLEET_JOBS, T=512, chunk=128,
-                        resilience=ResilienceConfig(
-                            fault_plane=FaultPlane.host_dropout(
-                                host=0, at_launch=2)))
-        assert res.degraded, "dropout was silent"
-        assert len(res.metrics) == len(FLEET_JOBS)
-        for j, (m0, m1) in enumerate(zip(base.metrics, res.metrics)):
-            if j not in res.degraded:
-                _metrics_equal([m0], [m1])
-        assert res.recovery_plan is not None
-        assert res.recovery_plan.action == "remesh"
-
-    def test_fleet_transient_launch_failure_retries(self):
-        base = run_fleet(FLEET_JOBS, T=512, chunk=128)
-        res = run_fleet(FLEET_JOBS, T=512, chunk=128,
-                        resilience=ResilienceConfig(
-                            fault_plane=FaultPlane.launch_fail(
-                                at_launch=1, fails=2)))
-        _metrics_equal(base.metrics, res.metrics)
+    @pytest.mark.parametrize("engine", list(ENGINES))
+    def test_transient_launch_failure_retries(self, engine):
+        run = ENGINES[engine]
+        base = run()
+        res = run(resilience=ResilienceConfig(
+            fault_plane=FaultPlane.launch_fail(at_launch=1, fails=2)))
+        for a, b in zip(_per_job(base), _per_job(res), strict=True):
+            _job_equal(a, b)
         assert res.n_fault_retries == 2
         assert res.degraded == {}
+
+
+def test_atlas_stream_closed_on_fault_between_launches(tmp_path,
+                                                       monkeypatch):
+    """A fault raised between launches (the record consumer failing on the
+    third launch record) leaves every record written before it in the
+    JSONL, and the sink closed, as a launch failure or a preemption
+    would."""
+    closed = []
+    real_close = StreamSink.close
+
+    def close(sink):
+        closed.append(sink)
+        real_close(sink)
+
+    monkeypatch.setattr(StreamSink, "close", close)
+    seen = []
+
+    def log(rec):
+        seen.append(rec)
+        if len(seen) == 3:
+            raise RuntimeError("record consumer failed")
+
+    path = tmp_path / "atlas_stream.jsonl"
+    with pytest.raises(RuntimeError, match="record consumer failed"):
+        sweep_lambda_max(ATLAS_CELLS, **ATLAS_KW, stream_path=str(path),
+                         stream_log=log)
+    assert len(closed) == 1
+    assert schema.read_stream_jsonl(str(path)) == seen
 
 
 # ---------------------------------------------------------------------------

@@ -20,6 +20,7 @@ from repro.fleet import (AtlasJob, FleetJob, PadDims, VerdictConfig,
                          sweep_lambda_max)
 from repro.fleet.engine import _make_stream_runner, make_group_launch
 from repro.obs import spans
+from repro.serving import ServingJob, run_serving
 
 
 @pytest.fixture
@@ -131,6 +132,25 @@ def test_fleet_spans_one_finalize_per_group():
     assert names.count("fleet.launch") == on.n_programs * 4
     assert "fleet.readout" not in names           # no early stop
     (job,) = [r for r in recs if r.name == "fleet.job"]
+    assert all(r.job == job.job for r in recs)
+
+
+@pytest.mark.fleet_smoke
+def test_serving_spans_come_from_the_shared_loop():
+    jobs = [ServingJob(trace="bursty", lam=3.0, seed=s) for s in (0, 1)]
+    off = run_serving(jobs, T=512, chunk=128)
+    spans.enable()
+    try:
+        on = run_serving(jobs, T=512, chunk=128)
+    finally:
+        recs = spans.collect()
+    assert on.metrics == off.metrics              # bit-identical
+    names = [r.name for r in recs]
+    assert names.count("serving.job") == names.count("serving.build") == 1
+    assert names.count("serving.group_setup") == on.n_programs
+    assert names.count("serving.finalize") == on.n_programs
+    assert names.count("serving.launch") == on.n_programs * 4
+    (job,) = [r for r in recs if r.name == "serving.job"]
     assert all(r.job == job.job for r in recs)
 
 
